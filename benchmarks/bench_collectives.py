@@ -56,6 +56,8 @@ def run():
 
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(os.path.dirname(__file__), "..", "src")
+    # the child lowers on 8 host devices; the chip stays with the parent
+    env["JAX_PLATFORMS"] = "cpu"
     t0 = time.perf_counter()
     r = subprocess.run(
         [sys.executable, "-c", _SCRIPT], capture_output=True, text=True,

@@ -6,6 +6,9 @@ import traceback
 
 
 def main() -> None:
+    from repro.compile_cache import use_compile_cache
+
+    use_compile_cache()
     from benchmarks import (
         bench_cluster,
         bench_coding,

@@ -120,6 +120,9 @@ class LocalCluster:
             str(register_delay),
         ]
         env = dict(os.environ)
+        # a chip belongs to one process: workers (whose 'matmul' payload
+        # imports jax) stay on the host CPU and leave the chip to the parent
+        env["JAX_PLATFORMS"] = "cpu"
         src = os.path.join(os.path.dirname(__file__), os.pardir, os.pardir)
         env["PYTHONPATH"] = os.pathsep.join(
             p for p in (os.path.abspath(src), env.get("PYTHONPATH")) if p

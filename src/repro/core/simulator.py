@@ -687,7 +687,7 @@ def sweep_coded(
 
     ``backend`` routes the order-statistic reduction through the
     :mod:`repro.kernels.sojourn_sweep` coded lanes — numpy reference,
-    jit+vmap JAX, or the Pallas kernel (CPU interpret mode) — recorded on
+    jit+vmap JAX, or the Pallas kernel (interpreted on CPU only) — recorded on
     the result for :attr:`~repro.core.planner.Plan.backend` provenance.
     """
     from repro.kernels import sojourn_sweep as _ss

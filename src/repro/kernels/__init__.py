@@ -3,7 +3,8 @@ architectures (the paper itself has no kernel-level contribution — these
 serve the LM substrate; see DESIGN.md §3 'Kernel policy').
 
 Each kernel ships as kernel.py (pl.pallas_call + BlockSpec), ops.py (jit'd
-wrapper) and ref.py (pure-jnp oracle), validated in interpret mode.
+wrapper) and ref.py (pure-jnp oracle), validated in interpret mode on CPU
+(``interpret`` defaults to the platform: compiled on TPU).
 """
 
 from repro.kernels.decode_attention.ops import decode_attention

@@ -13,6 +13,7 @@ cost the scheme actually pays instead of assuming it free.
 from __future__ import annotations
 
 import time
+from typing import Optional
 
 import numpy as np
 
@@ -20,12 +21,12 @@ BACKENDS = ("numpy", "jax", "pallas")
 
 
 def coded_combine(coeffs, blocks, *, backend: str = "numpy",
-                  interpret: bool = True):
+                  interpret: Optional[bool] = None):
     """(R, K) coefficient rows x (K, D) stacked blocks -> (R, D) coded rows.
 
     ``backend="numpy"`` is the host reference; ``"jax"`` / ``"pallas"``
     run the shared kernel body of :mod:`.kernel` (Pallas in interpret mode
-    by default so CPU-only tier-1 exercises it).
+    by default only on a CPU backend).
     """
     if backend == "numpy":
         return np.asarray(coeffs) @ np.asarray(blocks)
@@ -45,7 +46,7 @@ def coded_combine(coeffs, blocks, *, backend: str = "numpy",
 
 
 def decode_combine(weights, responses, *, backend: str = "numpy",
-                   interpret: bool = True):
+                   interpret: Optional[bool] = None):
     """Decode-side combine: same kernel, (k', m) weights x (m, d) responses."""
     return coded_combine(weights, responses, backend=backend,
                          interpret=interpret)
@@ -112,7 +113,7 @@ def measure_coding_overhead(
     repeats: int = 3,
     seed: int = 0,
     backend: str = "numpy",
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ) -> tuple[float, float]:
     """Wall-clock (encode_seconds, decode_seconds) of one coded job.
 

@@ -18,10 +18,13 @@ GSPMD).  Here:
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["decode_attention_kernel_call", "combine_splits"]
 
@@ -74,7 +77,7 @@ def combine_splits(m, l, acc):
 
 def decode_attention_kernel_call(
     q, k_cache, v_cache, cache_len, *, n_splits: int = 8, block_k: int = 128,
-    interpret: bool = True,
+    interpret: Optional[bool] = None,
 ):
     """q: (b, h, d); caches (b, S_max, h, d); cache_len scalar int32.
     Returns (b, h, d) in q.dtype."""
@@ -116,7 +119,7 @@ def decode_attention_kernel_call(
             jax.ShapeDtypeStruct((b * h, n_splits), jnp.float32),
             jax.ShapeDtypeStruct((b * h, n_splits, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf, lens)
     out = combine_splits(m, l, acc)  # (b*h, d)
     return out.reshape(b, h, d).astype(q.dtype)

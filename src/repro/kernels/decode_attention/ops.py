@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -18,7 +19,7 @@ __all__ = ["decode_attention"]
 )
 def decode_attention(q, k_cache, v_cache, cache_len, *, impl: str = "pallas",
                      n_splits: int = 8, block_k: int = 128,
-                     interpret: bool = True):
+                     interpret: Optional[bool] = None):
     """q: (b, h, d); caches (b, S_max, KV, d), H % KV == 0."""
     b, h, d = q.shape
     kv = k_cache.shape[2]

@@ -22,10 +22,13 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["flash_attention_kernel_call"]
 
@@ -82,7 +85,7 @@ def _attn_kernel(q_ref, k_ref, v_ref, o_ref, *, block_q, block_k, seq_kv,
 
 def flash_attention_kernel_call(
     q, k, v, *, causal: bool = True, q_offset: int = 0,
-    block_q: int = 128, block_k: int = 128, interpret: bool = True,
+    block_q: int = 128, block_k: int = 128, interpret: Optional[bool] = None,
 ):
     """q: (b, sq, h, d); k, v: (b, skv, h, d) (GQA pre-expanded).
 
@@ -117,6 +120,6 @@ def flash_attention_kernel_call(
         ],
         out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(qf, kf, vf)
     return out.reshape(b, h, sq, d).transpose(0, 2, 1, 3)

@@ -2,14 +2,15 @@
 
 Shape policy: pads seq to the block multiple, expands GQA KV heads, picks
 block sizes by sequence length, and dispatches kernel vs oracle by
-``impl`` ('pallas' | 'xla').  On this CPU container the kernel runs in
-interpret mode; on TPU set interpret=False (the BlockSpecs are already
-MXU/VMEM-aligned).
+``impl`` ('pallas' | 'xla').  ``interpret`` defaults to the platform:
+the interpreter on a CPU backend, the compiled kernel on TPU (the
+BlockSpecs are already MXU/VMEM-aligned).
 """
 
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -34,7 +35,7 @@ def _expand_kv(k, n_heads):
 )
 def flash_attention(
     q, k, v, *, causal: bool = True, q_offset: int = 0, impl: str = "pallas",
-    block_q: int = 128, block_k: int = 128, interpret: bool = True,
+    block_q: int = 128, block_k: int = 128, interpret: Optional[bool] = None,
 ):
     """q: (b, sq, H, d); k, v: (b, skv, KV, d) with H % KV == 0."""
     b, sq, h, d = q.shape

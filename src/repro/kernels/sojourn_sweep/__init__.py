@@ -8,8 +8,8 @@ accelerator from one shared-CRN draw matrix:
 * :mod:`.ref`    — numpy reference of the scan formulation (the oracle the
   event-driven simulator recursions are pinned against, bit-for-bit at f64);
 * :mod:`.kernel` — the shared jnp cell recursion, its ``lax.scan`` + vmap
-  backend, and the Pallas kernel (CPU ``interpret=True`` so tier-1 runs it
-  with no accelerator present);
+  backend, and the Pallas kernel (compiled on TPU; interpreted on a CPU
+  backend, which is how the tests run it);
 * :mod:`.ops`    — the batched entry point :func:`~.ops.sojourn_policy_cells`
   with backend dispatch (``numpy`` / ``jax`` / ``pallas``) and
   ``shard_map`` sharding of the cell axis across a device mesh.

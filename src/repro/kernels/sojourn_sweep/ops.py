@@ -12,11 +12,12 @@ on the requested backend —
   ``EmpiricalPlanner``'s bootstrap resamples ride the cell axis, so
   K=256 resamples spread across devices in one dispatch);
 * ``"pallas"`` — the Pallas kernel over a (cells, policies) grid,
-  ``interpret=True`` by default so CPU-only tier-1 exercises it.
+  compiled for the accelerator; in interpret mode only on a CPU backend
+  (see :func:`repro.kernels.platform.resolve_interpret`).
 
-The cell axis is padded to a multiple of the mesh size before sharding
-(dummy cells run ``n_groups=1`` on zero service draws) and sliced back
-afterwards.
+Sharding happens only when the caller passes a ``mesh``: the cell axis
+is then padded to a multiple of the mesh size (dummy cells run
+``n_groups=1`` on zero service draws) and sliced back afterwards.
 """
 
 from __future__ import annotations
@@ -28,7 +29,6 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec
 
 from . import kernel as _kernel
@@ -66,12 +66,10 @@ def hedge_mask(n_jobs: int, fraction: float) -> np.ndarray:
 def resolve_backend(backend: str) -> str:
     """Resolve the ``"auto"`` sweep backend: an accelerator device picks
     ``"jax"`` (the compiled vmap/shard_map path); CPU-only keeps the
-    bit-stable ``"numpy"`` event-driven path."""
+    bit-stable ``"numpy"`` event-driven path.  A backend that fails to
+    initialise raises: it never turns into ``"numpy"``."""
     if backend == "auto":
-        try:
-            devices = jax.devices()
-        except RuntimeError:
-            return "numpy"
+        devices = jax.devices()
         return "jax" if any(d.platform != "cpu" for d in devices) else "numpy"
     if backend not in BACKENDS:
         raise ValueError(
@@ -80,7 +78,7 @@ def resolve_backend(backend: str) -> str:
 
 
 def coded_completion_cells(times, ks, *, backend: str = "jax",
-                           interpret: bool = True):
+                           interpret: Optional[bool] = None):
     """k-of-N completion for a batch of coded cells on one backend.
 
     The coded twin of :func:`sojourn_policy_cells`: ``times`` (C, T, N)
@@ -115,19 +113,20 @@ def _sharded_cells_fn(mesh: Mesh, resolve: bool = True):
     spec_c3 = PartitionSpec("cells", None, None)
     spec_c2 = PartitionSpec("cells", None)
     rep = PartitionSpec()
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_kernel._cells_fn, resolve=resolve),
         mesh=mesh,
         in_specs=(rep, spec_c3, spec_c3, rep, spec_c2, rep, spec_c),
         out_specs=(spec_c3, spec_c2),
-        check_rep=False,
+        check_vma=False,
     )
     return jax.jit(fn)
 
 
 def sojourn_policy_cells(arrivals, svc, alt, kinds, thresholds, hedge_masks,
                          n_groups, *, backend: str = "jax",
-                         mesh: Optional[Mesh] = None, interpret: bool = True):
+                         mesh: Optional[Mesh] = None,
+                         interpret: Optional[bool] = None):
     """Evaluate all (cell, policy) sojourn recursions on one backend.
 
     Parameters
@@ -144,7 +143,10 @@ def sojourn_policy_cells(arrivals, svc, alt, kinds, thresholds, hedge_masks,
         with :func:`resolve_backend` first).
     mesh : optional device mesh; the cell axis is sharded over it
         (``"jax"`` backend only — the Pallas grid is device-local).
-    interpret : run the Pallas kernel in interpreter mode (CPU default).
+        Without one the dispatch runs on the default device, however
+        many devices are visible.
+    interpret : run the Pallas kernel in interpreter mode (default: only
+        on a CPU backend).
 
     Returns
     -------
@@ -183,8 +185,6 @@ def sojourn_policy_cells(arrivals, svc, alt, kinds, thresholds, hedge_masks,
                                             interpret=interpret,
                                             resolve=resolve)
 
-    if mesh is None and len(jax.devices()) > 1:
-        mesh = cells_mesh()
     if mesh is None:
         return _kernel.sojourn_cells_vmap(arrivals, svc, alt, kinds,
                                           thresholds, hedge_masks, n_groups,

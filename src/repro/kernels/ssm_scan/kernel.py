@@ -21,10 +21,13 @@ Validated in interpret mode against ref.py.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from repro.kernels.platform import resolve_interpret
 
 __all__ = ["ssd_scan_kernel_call"]
 
@@ -74,7 +77,7 @@ def _ssd_kernel(x_ref, dt_ref, alog_ref, b_ref, c_ref, dskip_ref, y_ref,
 
 
 def ssd_scan_kernel_call(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
-                         interpret: bool = True):
+                         interpret: Optional[bool] = None):
     """x (B,S,H,P); dt (B,S,H); a_log (H,); b,c (B,S,G,N); d_skip (H,).
     Returns (y (B,S,H,P), final_state (B,H,N,P))."""
     bsz, s, h, p = x.shape
@@ -112,7 +115,7 @@ def ssd_scan_kernel_call(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
             jax.ShapeDtypeStruct((bsz * h, s, p), x.dtype),
             jax.ShapeDtypeStruct((bsz * h, n, p), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(xf, dtf, alog_t, bf, cf, dskip_t)
     y = y.reshape(bsz, h, s, p).transpose(0, 2, 1, 3)
     st = st.reshape(bsz, h, n, p)

@@ -3,6 +3,7 @@
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 
@@ -14,7 +15,7 @@ __all__ = ["ssd_scan"]
 
 @functools.partial(jax.jit, static_argnames=("chunk", "impl", "interpret"))
 def ssd_scan(x, dt, a_log, b, c, d_skip, *, chunk: int = 128,
-             impl: str = "pallas", interpret: bool = True):
+             impl: str = "pallas", interpret: Optional[bool] = None):
     """Chunked SSD scan.  x (B,S,H,P); dt (B,S,H); a_log (H,);
     b, c (B,S,G,N); d_skip (H,).  Returns (y, final_state)."""
     if impl == "xla":

@@ -30,6 +30,7 @@ from repro.cluster import (
     make_matmul_spec,
     make_sleep_spec,
 )
+from repro.compile_cache import use_compile_cache
 from repro.core import CodingCandidate, PolicyCandidate
 from repro.serving.queueing import Request
 
@@ -173,6 +174,7 @@ def main(argv=None) -> int:
     ap.add_argument("--timeout", type=float, default=120.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
     summary = run_cluster(args)
     print(json.dumps(summary, indent=2))
     return 0

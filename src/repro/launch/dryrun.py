@@ -1,10 +1,12 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
 
-The two lines above MUST run before any other import (jax locks the device
-count at first init).  512 host devices stand in for 2 pods x 256 chips.
+The lines above MUST run before any other import (jax locks the platform
+and device count at first init).  512 host devices stand in for 2 pods x
+256 chips; pinning the CPU keeps the dry-run off any attached chip.
 
 Per cell this script:
   1. builds the production mesh (16x16 or 2x16x16) and the auto policy,
@@ -90,8 +92,6 @@ def run_cell(arch: str, shape: str, multi_pod: bool, out_dir: pathlib.Path,
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):  # older jax wraps the dict in a list
-        cost = cost[0] if cost else None
     print(f"[{tag}] memory_analysis: {mem}")
     flops = cost.get("flops", 0.0) if cost else 0.0
     print(f"[{tag}] cost_analysis: flops={flops:.3e} "
@@ -121,7 +121,10 @@ def main():
     ap.add_argument("--out", default="reports/dryrun")
     args = ap.parse_args()
 
+    from repro.compile_cache import use_compile_cache
     from repro.configs import ARCH_IDS, SHAPE_CELLS
+
+    use_compile_cache()
 
     out_dir = pathlib.Path(args.out)
     cells = []

@@ -5,8 +5,9 @@ batches, server groups = workers, and REPLICATING a request batch to r
 server groups lets the master take the FIRST response per batch — the
 paper's max-min completion applied to tail latency ('the tail at scale').
 
-The driver (a) actually runs prefill + decode on a small model to produce
-tokens, and (b) simulates the latency of a fleet of N server groups under
+The driver (a) actually runs prefill + decode to produce tokens (on the
+reduced model by default, at published widths with ``--full-width``),
+and (b) simulates the latency of a fleet of N server groups under
 the calibrated straggler model, BOTH as per-round batch-completion time
 (the serving twin of Fig. 2) and as per-request SOJOURN under Poisson
 arrivals at the configured utilization (the queueing-aware mode of
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.core import (
     ClusterSpec,
@@ -44,6 +46,7 @@ __all__ = ["ServeConfig", "run_serving"]
 @dataclasses.dataclass
 class ServeConfig:
     arch: str = "qwen2-0.5b"
+    reduced: bool = True  # False serves the published widths of ``arch``
     batch: int = 4
     prompt_len: int = 32
     gen_tokens: int = 16
@@ -81,10 +84,12 @@ class ServeConfig:
 
 
 def run_serving(sc: ServeConfig):
-    cfg = reduced_config(get_config(sc.arch))
-    if cfg.family in ("hybrid",):
-        pass  # supported via prefill
-    params = init_params(jax.random.PRNGKey(sc.seed), cfg)
+    cfg = get_config(sc.arch)
+    if sc.reduced:
+        cfg = reduced_config(cfg)
+    params = jax.jit(init_params, static_argnums=1)(
+        jax.random.PRNGKey(sc.seed), cfg
+    )
     shard = Shard.local()
     key = jax.random.PRNGKey(sc.seed + 1)
     prompts = jax.random.randint(
@@ -96,7 +101,10 @@ def run_serving(sc: ServeConfig):
         batch["patch_embeds"] = jax.random.normal(
             key, (sc.batch, cfg.n_patches, cfg.frontend_dim)
         )
-    logits, state = prefill(cfg, shard, params, batch, max_len=sc.max_len)
+    logits, state = jax.jit(
+        lambda p, b: prefill(cfg, shard, p, b, max_len=sc.max_len)
+    )(params, batch)
+    logits.block_until_ready()
     prefill_s = time.time() - t0
 
     step = jax.jit(
@@ -156,9 +164,14 @@ def main():
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--tokens", type=int, default=16)
     ap.add_argument("--batch", type=int, default=4)
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the published widths instead of the "
+                         "reduced model")
     args = ap.parse_args()
+    use_compile_cache()
     out = run_serving(ServeConfig(arch=args.arch, gen_tokens=args.tokens,
-                                  batch=args.batch))
+                                  batch=args.batch,
+                                  reduced=not args.full_width))
     print(f"prefill {out['prefill_s']*1e3:.1f}ms, "
           f"decode {out['decode_s']*1e3:.1f}ms for {args.tokens} tokens")
     print("generated tokens[0,:8]:", out["generated"][0, :8])

@@ -35,6 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import Checkpointer
+from repro.compile_cache import use_compile_cache
 from repro.configs import get_config, reduced_config
 from repro.configs.base import ArchConfig, ShapeCell
 from repro.core import (
@@ -446,6 +447,7 @@ def main():
     ap.add_argument("--compress", action="store_true")
     ap.add_argument("--ckpt-dir", default=None)
     args = ap.parse_args()
+    use_compile_cache()
     tc = TrainerConfig(
         arch=args.arch,
         steps=args.steps,

@@ -171,8 +171,6 @@ def analyze_compiled(compiled, cfg, cell, mesh, policy,
 
     chips = math.prod(mesh.devices.shape)
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):  # older jax wraps the dict in a list
-        cost = cost[0] if cost else {}
 
     hlo = compiled.as_text()
     # trip-count-aware walker (cost_analysis counts while bodies once)

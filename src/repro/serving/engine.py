@@ -77,6 +77,9 @@ _NO_TOKENS = np.empty(0, dtype=np.int32)
 @dataclasses.dataclass(frozen=True)
 class ServeEngineConfig:
     arch: str = "qwen2-0.5b"
+    # True: a tiny same-family model (tests, CPU smoke runs); False: the
+    # published widths of ``arch``
+    reduced: bool = True
     n_server_groups: int = 8  # the paper's N
     n_batches: int = 4  # the paper's B (replication r = N/B)
     batch_size: int = 4  # requests per batch (queueing: max batch size)
@@ -179,6 +182,14 @@ class ServeEngineConfig:
     miss_rate_target: Optional[float] = None
     # skip real prefill/decode (latency-only experiments, fast tests)
     execute_model: bool = True
+
+    def arch_config(self):
+        """The model the engine serves: ``arch`` at its published widths,
+        or its reduced twin when ``reduced``."""
+        from repro.configs import get_config, reduced_config
+
+        cfg = get_config(self.arch)
+        return reduced_config(cfg) if self.reduced else cfg
 
 
 @dataclasses.dataclass
@@ -333,13 +344,19 @@ class ReplicatedServingEngine:
         if sc.execute_model:
             import jax
 
-            from repro.configs import get_config, reduced_config
             from repro.models import Shard, decode_step, init_params, prefill
 
-            self.cfg = reduced_config(get_config(sc.arch))
-            self.params = init_params(jax.random.PRNGKey(sc.seed), self.cfg)
+            self.cfg = sc.arch_config()
+            # one compiled init program (cacheable) instead of hundreds of
+            # op-by-op compiles at full width
+            self.params = jax.jit(init_params, static_argnums=1)(
+                jax.random.PRNGKey(sc.seed), self.cfg
+            )
             self.shard = Shard.local()
-            self._prefill = prefill
+            self._prefill = jax.jit(
+                lambda p, b: prefill(self.cfg, self.shard, p, b,
+                                     max_len=sc.max_len)
+            )
             self._decode = jax.jit(
                 lambda p, s, t, c: decode_step(self.cfg, self.shard, p, s, t, c)
             )
@@ -641,10 +658,7 @@ class ReplicatedServingEngine:
         import jax.numpy as jnp
 
         sc = self.sc
-        logits, state = self._prefill(
-            self.cfg, self.shard, self.params, {"tokens": prompts},
-            max_len=sc.max_len,
-        )
+        logits, state = self._prefill(self.params, {"tokens": prompts})
         tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
         out = [tok]
         for i in range(sc.gen_tokens - 1):

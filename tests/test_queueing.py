@@ -1009,3 +1009,22 @@ def test_forced_move_bypasses_observed_sojourn_hysteresis():
     assert rp is not None
     assert rp.new_batches in (1, 2, 4)
     assert rp.predicted_old == math.inf
+
+
+# -- model width knob -----------------------------------------------------
+
+
+@pytest.mark.parametrize("published", [True, False])
+def test_engine_model_width_follows_reduced(published):
+    """``reduced=False`` serves qwen2-0.5b at its published widths (config
+    only: nothing is allocated); the default stays the tiny twin."""
+    from repro.launch.serve import ServeConfig
+
+    sc = ServeEngineConfig(reduced=not published)
+    cfg = sc.arch_config()
+    widths = (cfg.n_layers, cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+              cfg.d_ff, cfg.vocab_size)
+    published_widths = (24, 896, 14, 2, 4864, 151936)
+    assert (widths == published_widths) is published
+    assert cfg.family == "dense" and cfg.qkv_bias and cfg.tie_embeddings
+    assert ServeEngineConfig().reduced and ServeConfig().reduced

@@ -79,10 +79,7 @@ _SUBPROCESS_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax keeps it under experimental
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.core.replication import (ReplicationPlan, make_rdp_mesh,
         aggregate_gradients, REPLICA_AXIS, BATCH_AXIS)
     from repro.distributed.collectives import (hierarchical_allreduce,

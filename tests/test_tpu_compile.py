@@ -1,0 +1,131 @@
+"""Compile the planning path's kernels for a described TPU v5e chip.
+
+Nothing runs.  The TPU compiler that ships with jaxlib compiles each kernel
+at the shapes the planner dispatches, for a chip that is described rather
+than attached, and refuses what the chip would refuse: blocks that break
+the (8, 128) tiling, primitives Mosaic cannot lower, VMEM overflows.
+Interpret-mode tests on the CPU cannot see any of that.
+
+The topology is described inside a module fixture, never at import time:
+only one process may load the TPU library, and every test worker imports
+this file.
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.coded import kernel as CK
+from repro.kernels.sojourn_sweep import kernel as SK
+
+# fleet dispatch of the planning sweep: K=256 bootstrap resamples x the
+# largest split (B=200 replica-sets) x 300 jobs, one policy group of 2
+FLEET = dict(cells=256, jobs=300, groups=200, policies=2)
+# benchmarks/bench_coding.py: N=16 workers, 6000 trials, MDS s in {4, 8, 12}
+CODED = dict(cells=3, trials=6000, workers=16, block_dim=2048)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to a persistent cache but
+    cannot be read back without one; keep these compiles out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(one_chip, no_compile_cache):
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    return make
+
+
+def _sweep_args(spec):
+    c, j, g, p = (FLEET[k] for k in ("cells", "jobs", "groups", "policies"))
+    return (spec((j,)), spec((c, j, g)), spec((c, j, g)), spec((p,), jnp.int32),
+            spec((c, p)), spec((p, j), jnp.bool_), spec((c,), jnp.int32))
+
+
+def _assert_kernel(compiled):
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("resolve", [True, False])
+def test_sojourn_pallas_compiles_at_fleet_shape(spec, resolve):
+    compiled = SK.sojourn_cells_pallas.lower(
+        *_sweep_args(spec), interpret=False, resolve=resolve).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("resolve", [True, False])
+def test_sojourn_vmap_compiles_at_fleet_shape(spec, resolve):
+    compiled = SK.sojourn_cells_vmap.lower(*_sweep_args(spec),
+                                           resolve=resolve).compile()
+    c, j, p = FLEET["cells"], FLEET["jobs"], FLEET["policies"]
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= 4 * (c * p * j + c * p)
+
+
+def _coded_args(spec):
+    shape = (CODED["cells"], CODED["trials"], CODED["workers"])
+    return spec(shape), spec((CODED["cells"],), jnp.int32)
+
+
+def test_coded_pallas_compiles_at_bench_shape(spec):
+    compiled = SK.coded_cells_pallas.lower(*_coded_args(spec),
+                                           interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+def test_coded_vmap_compiles_at_bench_shape(spec):
+    compiled = SK.coded_cells_vmap.lower(*_coded_args(spec)).compile()
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= 4 * CODED["cells"] * CODED["trials"]
+
+
+# (rows, k): MDS encode (N x k) and decode (k x k) at s=4, and the cyclic
+# gradient code's one-row decode
+COMBINE_SHAPES = [(16, 12), (12, 12), (1, 14)]
+
+
+@pytest.mark.parametrize("rows,k", COMBINE_SHAPES)
+def test_combine_pallas_compiles_at_bench_shape(spec, rows, k):
+    compiled = CK.combine_pallas.lower(
+        spec((rows, k)), spec((k, CODED["block_dim"])),
+        interpret=False).compile()
+    _assert_kernel(compiled)
+
+
+@pytest.mark.parametrize("rows,k", COMBINE_SHAPES)
+def test_combine_jit_compiles_at_bench_shape(spec, rows, k):
+    compiled = CK.combine_jit.lower(
+        spec((rows, k)), spec((k, CODED["block_dim"]))).compile()
+    out_bytes = compiled.memory_analysis().output_size_in_bytes
+    assert out_bytes >= 4 * rows * CODED["block_dim"]
