@@ -47,4 +47,5 @@ def combine_pallas(coeffs, blocks, interpret=None):
         _combine_kernel,
         out_shape=jax.ShapeDtypeStruct((n_rows, d), blocks.dtype),
         interpret=resolve_interpret(interpret),
+        name="coded_combine",
     )(coeffs, blocks)

@@ -246,6 +246,7 @@ def coded_cells_pallas(times, ks, interpret=None):
         out_specs=pl.BlockSpec((1, 1, n_trials), lambda c: (c, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n_cells, 1, n_trials), times.dtype),
         interpret=resolve_interpret(interpret),
+        name="coded_cells",
     )(ks, jnp.swapaxes(times, 1, 2))
     return out[:, 0, :]
 
@@ -298,6 +299,7 @@ def sojourn_cells_pallas(arrivals, svc, alt, kinds, thresholds, hedge_masks,
             jax.ShapeDtypeStruct((n_cells, n_pol, 1, 128), jnp.int32),
         ],
         interpret=resolve_interpret(interpret),
+        name="sojourn_cells",
     )(kinds, thresholds.reshape(-1), n_groups, arrivals,
       hedge_masks.astype(jnp.int32).reshape(-1), svc, alt)
     return out[:, :, 0, :], extra[:, :, 0, 0]

@@ -39,6 +39,7 @@ from collections import deque
 from typing import Optional
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core import (
     ClusterSpec,
@@ -72,6 +73,18 @@ from repro.serving.queueing import (
 __all__ = ["ServeEngineConfig", "RequestStats", "ReplicatedServingEngine"]
 
 _NO_TOKENS = np.empty(0, dtype=np.int32)
+
+# Profiler spans of the serving host path (docs/architecture.md, "Tracing
+# the serving engine").  Static names: with no profiler running, a span
+# costs one object construction.
+PREFIX = "repro."
+_SERVE = PREFIX + "serve"
+_JOB = PREFIX + "job"
+_PROMPTS = PREFIX + "prompts"
+_PREFILL = PREFIX + "prefill"
+_DECODE = PREFIX + "decode"
+_SAMPLE = PREFIX + "sample"
+_FETCH = PREFIX + "fetch"
 
 
 @dataclasses.dataclass(frozen=True)
@@ -353,13 +366,17 @@ class ReplicatedServingEngine:
                 jax.random.PRNGKey(sc.seed), self.cfg
             )
             self.shard = Shard.local()
-            self._prefill = jax.jit(
-                lambda p, b: prefill(self.cfg, self.shard, p, b,
-                                     max_len=sc.max_len)
-            )
-            self._decode = jax.jit(
-                lambda p, s, t, c: decode_step(self.cfg, self.shard, p, s, t, c)
-            )
+
+            # named, so that the device trace shows jit_serve_prefill and
+            # jit_serve_decode
+            def serve_prefill(p, b):
+                return prefill(self.cfg, self.shard, p, b, max_len=sc.max_len)
+
+            def serve_decode(p, s, t, c):
+                return decode_step(self.cfg, self.shard, p, s, t, c)
+
+            self._prefill = jax.jit(serve_prefill)
+            self._decode = jax.jit(serve_decode)
             self._prompt_key = jax.random.PRNGKey(sc.seed + 3)
         else:
             self.cfg = None
@@ -658,16 +675,21 @@ class ReplicatedServingEngine:
         import jax.numpy as jnp
 
         sc = self.sc
-        logits, state = self._prefill(self.params, {"tokens": prompts})
-        tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+        with TraceAnnotation(_PREFILL):
+            logits, state = self._prefill(self.params, {"tokens": prompts})
+        with TraceAnnotation(_SAMPLE):
+            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
         out = [tok]
         for i in range(sc.gen_tokens - 1):
-            logits, state = self._decode(
-                self.params, state, tok, jnp.int32(sc.prompt_len + i)
-            )
-            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+            with TraceAnnotation(_DECODE):
+                logits, state = self._decode(
+                    self.params, state, tok, jnp.int32(sc.prompt_len + i)
+                )
+            with TraceAnnotation(_SAMPLE):
+                tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
             out.append(tok)
-        return np.asarray(jnp.concatenate(out, axis=1))
+        with TraceAnnotation(_FETCH):
+            return np.asarray(jnp.concatenate(out, axis=1))
 
     def _generate_for_job(self, job: BatchJob) -> None:
         """Run real prefill+decode for a completed batch (event path).
@@ -678,14 +700,16 @@ class ReplicatedServingEngine:
         import jax
 
         sc = self.sc
-        rows = [
-            jax.random.randint(
-                jax.random.fold_in(self._prompt_key, req.request_id),
-                (sc.prompt_len,), 0, self.cfg.vocab_size,
-            )
-            for req in job.requests
-        ]
-        tokens = self._generate(jax.numpy.stack(rows))
+        with TraceAnnotation(_PROMPTS):
+            rows = [
+                jax.random.randint(
+                    jax.random.fold_in(self._prompt_key, req.request_id),
+                    (sc.prompt_len,), 0, self.cfg.vocab_size,
+                )
+                for req in job.requests
+            ]
+            prompts = jax.numpy.stack(rows)
+        tokens = self._generate(prompts)
         for k, req in enumerate(job.requests):
             self._tokens[req.request_id] = tokens[k]
 
@@ -769,78 +793,82 @@ class ReplicatedServingEngine:
 
     def _on_job_complete(self, job: BatchJob) -> Optional[dict]:
         """Telemetry + model work + (maybe) a drain-then-swap re-plan."""
-        work = self._work(job.size)
-        # censoring-correct per-replica telemetry across the live attempt,
-        # relaunch-discarded attempts, and clones/hedges — shared with the
-        # wall-clock cluster coordinator (queueing.job_observations)
-        for times, censored in job_observations(job):
-            self.tuner.observe(times / work, censored=censored)
-        self.tuner.observe_sojourn(
-            np.array([req.sojourn for req in job.requests])
-        )
-        # PER-REQUEST miss accounting, matching the drop path's granularity
-        # (a batch-level (n_missed, n_batch) observation would weight each
-        # batch equally however many requests it resolved — partial batches
-        # then skew the windowed rate) and carrying the SLO class so
-        # per-class breach detection sees served outcomes too
-        for req in job.requests:
-            if math.isfinite(req.deadline):
-                self.tuner.observe_deadline_misses(
-                    int(req.completion > req.deadline), 1, slo=req.slo
-                )
-        self._formations.append(job.formed_at)
-        if len(self._formations) >= 2:
-            # jobs complete out of formation order (slow sets finish late),
-            # so span the window by max-min, not last-first
-            span = max(self._formations) - min(self._formations)
-            if span > 0:
-                self.tuner.observe_load((len(self._formations) - 1) / span)
-        if self.sc.execute_model:
-            self._generate_for_job(job)
-        if self.sc.tuner:
-            rp = self.tuner.maybe_replan()
-            if rp is not None:
-                self.plan = self.tuner.apply(rp)
-                # adopt the mitigation the winning score assumed: when the
-                # re-plan swept (B, policy) or (B, trigger) cells, run what
-                # it scored — including "don't mitigate at this B" (None)
-                if rp.plan is not None and rp.plan.objective.coding:
-                    self.last_coding = rp.plan.coding
-                if rp.plan is not None and rp.plan.objective.slo_classes:
-                    # serving re-plan: adopt the whole (policy, max_wait,
-                    # shed) cell and ship the new queue policy to the
-                    # quiesce point alongside the new fabric
-                    self._adopt_serving(rp.plan)
-                    return {
-                        "n_groups": self.plan.n_batches,
-                        "policy": self._queue_policy(),
-                    }
-                if rp.plan is not None and rp.plan.objective.policies:
-                    self._adopt_policy(rp.plan)
-                elif (
-                    rp.plan is not None
-                    and rp.plan.objective.speculation_quantiles
-                ):
-                    self.speculation_quantile = rp.plan.speculation_quantile
-                return {"n_groups": self.plan.n_batches}
-            # no B move, but the last evaluated sweep may still have found
-            # a better policy/trigger AT the current B — adopting it needs
-            # no drain/reconfig, so it is free (cooldown paces evaluations)
-            lp = self.tuner.last_plan
-            if lp is not None and lp.objective.coding:
-                self.last_coding = lp.coding
-            if lp is not None and lp.n_batches == self.plan.n_batches:
-                if lp.objective.slo_classes:
-                    self._adopt_serving(lp)
-                    # same-B adoption needs no drain: max_wait/cap are
-                    # scalar knobs the live master swaps in place
-                    if self.last_master is not None:
-                        self.last_master.swap_policy(self._queue_policy())
-                elif lp.objective.policies:
-                    self._adopt_policy(lp)
-                elif lp.objective.speculation_quantiles:
-                    self.speculation_quantile = lp.speculation_quantile
-        return None
+        with TraceAnnotation(_JOB):
+            work = self._work(job.size)
+            # censoring-correct per-replica telemetry across the live
+            # attempt, relaunch-discarded attempts, and clones/hedges —
+            # shared with the wall-clock cluster coordinator
+            # (queueing.job_observations)
+            for times, censored in job_observations(job):
+                self.tuner.observe(times / work, censored=censored)
+            self.tuner.observe_sojourn(
+                np.array([req.sojourn for req in job.requests])
+            )
+            # PER-REQUEST miss accounting, matching the drop path's granularity
+            # (a batch-level (n_missed, n_batch) observation would weight each
+            # batch equally however many requests it resolved — partial batches
+            # then skew the windowed rate) and carrying the SLO class so
+            # per-class breach detection sees served outcomes too
+            for req in job.requests:
+                if math.isfinite(req.deadline):
+                    self.tuner.observe_deadline_misses(
+                        int(req.completion > req.deadline), 1, slo=req.slo
+                    )
+            self._formations.append(job.formed_at)
+            if len(self._formations) >= 2:
+                # jobs complete out of formation order (slow sets finish late),
+                # so span the window by max-min, not last-first
+                span = max(self._formations) - min(self._formations)
+                if span > 0:
+                    self.tuner.observe_load((len(self._formations) - 1) / span)
+            if self.sc.execute_model:
+                self._generate_for_job(job)
+            if self.sc.tuner:
+                rp = self.tuner.maybe_replan()
+                if rp is not None:
+                    self.plan = self.tuner.apply(rp)
+                    # adopt the mitigation the winning score assumed: when the
+                    # re-plan swept (B, policy) or (B, trigger) cells, run what
+                    # it scored — including "don't mitigate at this B" (None)
+                    if rp.plan is not None and rp.plan.objective.coding:
+                        self.last_coding = rp.plan.coding
+                    if rp.plan is not None and rp.plan.objective.slo_classes:
+                        # serving re-plan: adopt the whole (policy, max_wait,
+                        # shed) cell and ship the new queue policy to the
+                        # quiesce point alongside the new fabric
+                        self._adopt_serving(rp.plan)
+                        return {
+                            "n_groups": self.plan.n_batches,
+                            "policy": self._queue_policy(),
+                        }
+                    if rp.plan is not None and rp.plan.objective.policies:
+                        self._adopt_policy(rp.plan)
+                    elif (
+                        rp.plan is not None
+                        and rp.plan.objective.speculation_quantiles
+                    ):
+                        self.speculation_quantile = (
+                            rp.plan.speculation_quantile
+                        )
+                    return {"n_groups": self.plan.n_batches}
+                # no B move, but the last evaluated sweep may still have found
+                # a better policy/trigger AT the current B — adopting it needs
+                # no drain/reconfig, so it is free (cooldown paces evaluations)
+                lp = self.tuner.last_plan
+                if lp is not None and lp.objective.coding:
+                    self.last_coding = lp.coding
+                if lp is not None and lp.n_batches == self.plan.n_batches:
+                    if lp.objective.slo_classes:
+                        self._adopt_serving(lp)
+                        # same-B adoption needs no drain: max_wait/cap are
+                        # scalar knobs the live master swaps in place
+                        if self.last_master is not None:
+                            self.last_master.swap_policy(self._queue_policy())
+                    elif lp.objective.policies:
+                        self._adopt_policy(lp)
+                    elif lp.objective.speculation_quantiles:
+                        self.speculation_quantile = lp.speculation_quantile
+            return None
 
     def serve(
         self,
@@ -865,96 +893,99 @@ class ReplicatedServingEngine:
         class deadline applies where neither ``deadlines`` nor the config's
         uniform ``deadline`` does.
         """
-        sc = self.sc
-        process = arrivals if arrivals is not None else self._default_arrivals()
-        labels: Optional[list[str]] = None
-        if sc.slo_classes and hasattr(process, "sample_with_classes"):
-            times, labels = process.sample_with_classes(
-                self._arrival_rng, n_requests, start=self.clock
+        with TraceAnnotation(_SERVE):
+            sc = self.sc
+            process = (
+                arrivals if arrivals is not None else self._default_arrivals()
             )
-        else:
-            times = process.sample(
-                self._arrival_rng, n_requests, start=self.clock
-            )
-            if sc.slo_classes:
-                shares = np.array(
-                    [c.share for c in sc.slo_classes], dtype=float
+            labels: Optional[list[str]] = None
+            if sc.slo_classes and hasattr(process, "sample_with_classes"):
+                times, labels = process.sample_with_classes(
+                    self._arrival_rng, n_requests, start=self.clock
                 )
-                idx = self._arrival_rng.choice(
-                    len(shares), size=n_requests, p=shares / shares.sum()
+            else:
+                times = process.sample(
+                    self._arrival_rng, n_requests, start=self.clock
                 )
-                labels = [sc.slo_classes[i].name for i in idx]
-        if deadlines is None and sc.deadline is not None:
-            deadlines = np.full(n_requests, sc.deadline)
-        if deadlines is not None and len(deadlines) != n_requests:
-            raise ValueError(
-                f"deadlines length {len(deadlines)} != {n_requests}"
+                if sc.slo_classes:
+                    shares = np.array(
+                        [c.share for c in sc.slo_classes], dtype=float
+                    )
+                    idx = self._arrival_rng.choice(
+                        len(shares), size=n_requests, p=shares / shares.sum()
+                    )
+                    labels = [sc.slo_classes[i].name for i in idx]
+            if deadlines is None and sc.deadline is not None:
+                deadlines = np.full(n_requests, sc.deadline)
+            if deadlines is not None and len(deadlines) != n_requests:
+                raise ValueError(
+                    f"deadlines length {len(deadlines)} != {n_requests}"
+                )
+            if priorities is not None and len(priorities) != n_requests:
+                raise ValueError(
+                    f"priorities length {len(priorities)} != {n_requests}"
+                )
+            class_deadline = (
+                {c.name: c.deadline for c in sc.slo_classes}
+                if sc.slo_classes
+                else {}
             )
-        if priorities is not None and len(priorities) != n_requests:
-            raise ValueError(
-                f"priorities length {len(priorities)} != {n_requests}"
-            )
-        class_deadline = (
-            {c.name: c.deadline for c in sc.slo_classes}
-            if sc.slo_classes
-            else {}
-        )
 
-        def _deadline(i: int, t: float) -> float:
-            if deadlines is not None:
-                return t + float(deadlines[i])
-            if labels is not None:
-                rel = class_deadline.get(labels[i])
-                if rel is not None:
-                    return t + float(rel)
-            return math.inf
+            def _deadline(i: int, t: float) -> float:
+                if deadlines is not None:
+                    return t + float(deadlines[i])
+                if labels is not None:
+                    rel = class_deadline.get(labels[i])
+                    if rel is not None:
+                        return t + float(rel)
+                return math.inf
 
-        requests = [
-            Request(
-                request_id=self._next_id + i,
-                arrival=float(t),
-                deadline=_deadline(i, float(t)),
-                priority=(
-                    float(priorities[i]) if priorities is not None else 0.0
-                ),
-                slo=labels[i] if labels is not None else "",
+            requests = [
+                Request(
+                    request_id=self._next_id + i,
+                    arrival=float(t),
+                    deadline=_deadline(i, float(t)),
+                    priority=(
+                        float(priorities[i]) if priorities is not None else 0.0
+                    ),
+                    slo=labels[i] if labels is not None else "",
+                )
+                for i, t in enumerate(times)
+            ]
+            self._next_id += n_requests
+            master = EventDrivenMaster(
+                n_groups=self.plan.n_batches,
+                service_sampler=self._service_sampler,
+                policy=self._queue_policy(),
+                clock=self.clock,
+                on_job_complete=self._on_job_complete,
+                speculation=self._speculation_policy(),
+                # a dropped request resolved as a miss without reaching any job
+                # callback: stream it into the tuner AS IT HAPPENS, per request
+                # and class-attributed (see _on_drop)
+                on_drop=self._on_drop,
             )
-            for i, t in enumerate(times)
-        ]
-        self._next_id += n_requests
-        master = EventDrivenMaster(
-            n_groups=self.plan.n_batches,
-            service_sampler=self._service_sampler,
-            policy=self._queue_policy(),
-            clock=self.clock,
-            on_job_complete=self._on_job_complete,
-            speculation=self._speculation_policy(),
-            # a dropped request resolved as a miss without reaching any job
-            # callback: stream it into the tuner AS IT HAPPENS, per request
-            # and class-attributed (see _on_drop)
-            on_drop=self._on_drop,
-        )
-        self._tokens = {}
-        # visible to _on_job_complete DURING the run: same-B serving
-        # re-plans swap the live master's queue policy in place
-        self.last_master = master
-        for req in requests:
-            master.submit(req)
-        master.run()
-        self.clock = master.clock
-        return [
-            RequestStats(
-                request_id=req.request_id,
-                arrival=req.arrival,
-                completion=req.completion,
-                tokens=self._tokens.get(req.request_id, _NO_TOKENS),
-                dispatched=req.dispatched,
-                deadline=req.deadline,
-                dropped=req.dropped,
-                slo=req.slo,
-            )
-            for req in requests
-        ]
+            self._tokens = {}
+            # visible to _on_job_complete DURING the run: same-B serving
+            # re-plans swap the live master's queue policy in place
+            self.last_master = master
+            for req in requests:
+                master.submit(req)
+            master.run()
+            self.clock = master.clock
+            return [
+                RequestStats(
+                    request_id=req.request_id,
+                    arrival=req.arrival,
+                    completion=req.completion,
+                    tokens=self._tokens.get(req.request_id, _NO_TOKENS),
+                    dispatched=req.dispatched,
+                    deadline=req.deadline,
+                    dropped=req.dropped,
+                    slo=req.slo,
+                )
+                for req in requests
+            ]
 
     def run_load(
         self,
