@@ -83,7 +83,6 @@ _JOB = PREFIX + "job"
 _PROMPTS = PREFIX + "prompts"
 _PREFILL = PREFIX + "prefill"
 _DECODE = PREFIX + "decode"
-_SAMPLE = PREFIX + "sample"
 _FETCH = PREFIX + "fetch"
 
 
@@ -356,6 +355,7 @@ class ReplicatedServingEngine:
         self._formations: deque[float] = deque(maxlen=32)
         if sc.execute_model:
             import jax
+            import jax.numpy as jnp
 
             from repro.models import Shard, decode_step, init_params, prefill
 
@@ -367,17 +367,48 @@ class ReplicatedServingEngine:
             )
             self.shard = Shard.local()
 
-            # named, so that the device trace shows jit_serve_prefill and
-            # jit_serve_decode
+            # one program per call, each named, so that the device trace
+            # shows jit_serve_prompts, jit_serve_prefill, jit_serve_decode
+            # and jit_serve_join; prefill and decode return the greedy
+            # pick of the last logits, a (b, 1) int32 token, and the state
+            def pick(logits):
+                # the barrier keeps the bfloat16 logits a buffer of their
+                # own, as when the pick ran eagerly: fused into the
+                # unembedding's matmul, the pick on a TPU v5e chose other
+                # tokens than the eager pick
+                logits = jax.lax.optimization_barrier(logits)
+                return jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+
             def serve_prefill(p, b):
-                return prefill(self.cfg, self.shard, p, b, max_len=sc.max_len)
+                logits, state = prefill(self.cfg, self.shard, p, b,
+                                        max_len=sc.max_len)
+                return pick(logits), state
 
             def serve_decode(p, s, t, c):
-                return decode_step(self.cfg, self.shard, p, s, t, c)
+                logits, state = decode_step(self.cfg, self.shard, p, s, t, c)
+                return pick(logits), state
+
+            # a request's prompt depends on its id alone: each row is
+            # randint(fold_in(key, id)), as if drawn on its own
+            def serve_prompts(key, ids):
+                return jax.vmap(lambda i: jax.random.randint(
+                    jax.random.fold_in(key, i), (sc.prompt_len,), 0,
+                    self.cfg.vocab_size))(ids)
+
+            # one program: an eager concatenate of more than 16 arrays
+            # runs as several
+            def serve_join(toks):
+                return jnp.concatenate(toks, axis=1)
 
             self._prefill = jax.jit(serve_prefill)
             self._decode = jax.jit(serve_decode)
+            self._prompts = jax.jit(serve_prompts)
+            self._join = jax.jit(serve_join)
             self._prompt_key = jax.random.PRNGKey(sc.seed + 3)
+            # the decode steps' cache lengths, on the device once
+            self._positions = jax.device_put([
+                np.int32(sc.prompt_len + i) for i in range(sc.gen_tokens - 1)
+            ])
         else:
             self.cfg = None
             self.params = None
@@ -672,24 +703,15 @@ class ReplicatedServingEngine:
 
     # -- real model work -----------------------------------------------------
     def _generate(self, prompts) -> np.ndarray:
-        import jax.numpy as jnp
-
-        sc = self.sc
         with TraceAnnotation(_PREFILL):
-            logits, state = self._prefill(self.params, {"tokens": prompts})
-        with TraceAnnotation(_SAMPLE):
-            tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+            tok, state = self._prefill(self.params, {"tokens": prompts})
         out = [tok]
-        for i in range(sc.gen_tokens - 1):
+        for pos in self._positions:
             with TraceAnnotation(_DECODE):
-                logits, state = self._decode(
-                    self.params, state, tok, jnp.int32(sc.prompt_len + i)
-                )
-            with TraceAnnotation(_SAMPLE):
-                tok = jnp.argmax(logits[:, -1], -1)[:, None].astype(jnp.int32)
+                tok, state = self._decode(self.params, state, tok, pos)
             out.append(tok)
         with TraceAnnotation(_FETCH):
-            return np.asarray(jnp.concatenate(out, axis=1))
+            return np.asarray(self._join(out))
 
     def _generate_for_job(self, job: BatchJob) -> None:
         """Run real prefill+decode for a completed batch (event path).
@@ -697,18 +719,9 @@ class ReplicatedServingEngine:
         Prompts are keyed by request id (fold_in), so WHAT is generated for a
         request is invariant to how traffic got batched or replicated.
         """
-        import jax
-
-        sc = self.sc
         with TraceAnnotation(_PROMPTS):
-            rows = [
-                jax.random.randint(
-                    jax.random.fold_in(self._prompt_key, req.request_id),
-                    (sc.prompt_len,), 0, self.cfg.vocab_size,
-                )
-                for req in job.requests
-            ]
-            prompts = jax.numpy.stack(rows)
+            ids = np.array([req.request_id for req in job.requests], np.uint32)
+            prompts = self._prompts(self._prompt_key, ids)
         tokens = self._generate(prompts)
         for k, req in enumerate(job.requests):
             self._tokens[req.request_id] = tokens[k]

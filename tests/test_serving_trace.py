@@ -4,14 +4,14 @@ its device programs.
 A tiny reduced engine serves one call under ``jax.profiler`` on the CPU; the
 trace is read back with ``jax.profiler.ProfileData``.  Each completed job
 must show one ``repro.job``, ``repro.prompts``, ``repro.prefill`` and
-``repro.fetch`` span, ``gen_tokens - 1`` ``repro.decode`` spans and
-``gen_tokens`` ``repro.sample`` spans, all inside the call's
-``repro.serve`` span.
+``repro.fetch`` span and ``gen_tokens - 1`` ``repro.decode`` spans, all
+inside the call's ``repro.serve`` span, and launch one device program per
+prompt draw, prefill, decode step and fetch.
 """
 
 import glob
 import os
-from collections import defaultdict
+from collections import Counter, defaultdict
 
 import jax
 import jax.numpy as jnp
@@ -21,33 +21,33 @@ import pytest
 from repro.serving import ReplicatedServingEngine, ServeEngineConfig
 from repro.serving.engine import PREFIX
 
-GEN_TOKENS = 4
+GEN_TOKENS = 20
 N_REQUESTS = 6
 BATCH = 2
 PER_JOB = {"repro.job": 1, "repro.prompts": 1, "repro.prefill": 1,
-           "repro.decode": GEN_TOKENS - 1, "repro.sample": GEN_TOKENS,
-           "repro.fetch": 1}
+           "repro.decode": GEN_TOKENS - 1, "repro.fetch": 1}
 
 
 def _engine():
     return ReplicatedServingEngine(ServeEngineConfig(
         n_server_groups=4, n_batches=2, batch_size=BATCH, prompt_len=8,
-        gen_tokens=GEN_TOKENS, max_len=16, utilization=0.5, seed=5))
+        gen_tokens=GEN_TOKENS, max_len=32, utilization=0.5, seed=5))
 
 
 def _host_events(log_dir):
-    """(spans: name -> sorted [(start, end)], names of every host event)."""
+    """(spans: name -> sorted [(start, end)], count of every host event's
+    name)."""
     from jax.profiler import ProfileData
 
     path, = glob.glob(os.path.join(log_dir, "**", "*.xplane.pb"),
                       recursive=True)
-    spans, names = defaultdict(list), set()
+    spans, names = defaultdict(list), Counter()
     for plane in ProfileData.from_file(path).planes:
         if not plane.name.startswith("/host:"):
             continue
         for line in plane.lines:
             for ev in line.events:
-                names.add(ev.name)
+                names[ev.name] += 1
                 if ev.name.startswith(PREFIX):
                     spans[ev.name].append(
                         (ev.start_ns, ev.start_ns + ev.duration_ns))
@@ -100,17 +100,30 @@ def test_spans_nest_inside_serve(served, name):
     assert all(_inside(iv, serve) for iv in spans[name])
 
 
-def test_decode_and_sample_spans_alternate(served):
-    """Within a job: prefill, sample, then (decode, sample) per token,
-    then fetch."""
+def test_prompt_prefill_decode_fetch_spans_in_order(served):
+    """Within a job: prompts, prefill, a decode per further token, then
+    fetch; the greedy pick has no span, it runs inside prefill and decode."""
     _, _, _, spans, _ = served
-    model = sorted((s, name) for name in ("repro.prefill", "repro.decode",
-                                          "repro.sample", "repro.fetch")
+    assert "repro.sample" not in spans
+    model = sorted((s, name) for name in ("repro.prompts", "repro.prefill",
+                                          "repro.decode", "repro.fetch")
                    for s, _ in spans[name])
-    want = (["repro.prefill", "repro.sample"]
-            + ["repro.decode", "repro.sample"] * (GEN_TOKENS - 1)
-            + ["repro.fetch"])
+    want = (["repro.prompts", "repro.prefill"]
+            + ["repro.decode"] * (GEN_TOKENS - 1) + ["repro.fetch"])
     assert [n for _, n in model] == want * (N_REQUESTS // BATCH)
+
+
+def test_device_programs_per_job(served):
+    """At most ``gen_tokens + 8`` program launches a job: the prompt draw,
+    prefill, a decode step per further token and the fetch's concatenation
+    are one launch each (an eager greedy pick would be about a dozen a
+    token, and an eager concatenate of more than 16 tokens several)."""
+    _, _, _, spans, names = served
+    launches = sum(n for name, n in names.items()
+                   if name.endswith("Executable::Execute"))
+    jobs = len(spans["repro.job"])
+    assert launches == jobs * (GEN_TOKENS + 2)
+    assert launches / jobs <= GEN_TOKENS + 8
 
 
 def test_tracing_leaves_tokens_unchanged(served):
@@ -123,7 +136,9 @@ def test_tracing_leaves_tokens_unchanged(served):
 def test_programs_have_stable_names(served):
     engine, _, _, _, names = served
     assert {"PjitFunction(serve_prefill)",
-            "PjitFunction(serve_decode)"} <= names
+            "PjitFunction(serve_decode)",
+            "PjitFunction(serve_prompts)",
+            "PjitFunction(serve_join)"} <= names.keys()
     prompts = jnp.zeros((BATCH, 8), jnp.int32)
     text = engine._prefill.lower(engine.params,
                                  {"tokens": prompts}).as_text()

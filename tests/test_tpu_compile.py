@@ -129,3 +129,34 @@ def test_combine_jit_compiles_at_bench_shape(spec, rows, k):
         spec((rows, k)), spec((k, CODED["block_dim"]))).compile()
     out_bytes = compiled.memory_analysis().output_size_in_bytes
     assert out_bytes >= 4 * rows * CODED["block_dim"]
+
+
+@pytest.fixture(scope="module")
+def serving(spec):
+    """A reduced serving engine's prefill and decode programs, compiled for
+    the described chip at batch 8."""
+    from repro.serving import ReplicatedServingEngine, ServeEngineConfig
+
+    engine = ReplicatedServingEngine(ServeEngineConfig(
+        batch_size=8, prompt_len=16, gen_tokens=4, max_len=32))
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = shapes(engine.params)
+    prompts = {"tokens": spec((8, 16), jnp.int32)}
+    tok, state = jax.eval_shape(engine._prefill, params, prompts)
+    return {
+        "prefill": engine._prefill.lower(params, prompts).compile(),
+        "decode": engine._decode.lower(params, shapes(state), shapes(tok),
+                                       spec((), jnp.int32)).compile(),
+    }
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_serving_pick_reads_materialised_logits(serving, program):
+    """The greedy pick inside the serving programs reduces the bfloat16
+    logits as a buffer of their own, as the eager pick did: no fused
+    computation holds both the unembedding's matmul and the argmax."""
+    blocks = serving[program].as_text().split("\n\n")
+    assert any("iota(" in b and "reduce(" in b for b in blocks)
+    assert not [b.splitlines()[0] for b in blocks
+                if "convolution(" in b and "iota(" in b and "reduce(" in b]
