@@ -6,7 +6,8 @@ reference reads: per-layer tensors stacked on a leading layer axis,
 projection matrices as (fan_in, fan_out).  ``to_engine`` reshapes them into
 the parameter tree that ``repro.models`` serves (``embed.tokens``,
 ``blocks.{ln1,attn,ln2,mlp}``, ``final_norm``); it is the one place that
-knows the program's layout.
+knows the program's layout.  ``arch(cfg)`` is the program's model that the
+engine must serve for the configuration.
 """
 
 from __future__ import annotations
@@ -22,6 +23,32 @@ def dims(cfg: dict) -> dict:
             "L": cfg["num_hidden_layers"], "H": h,
             "KV": cfg["num_key_value_heads"], "hd": d // h,
             "V": cfg["vocab_size"]}
+
+
+# the published widths the file states, by the program's field names
+_WIDTHS = {"d_model": "hidden_size", "n_heads": "num_attention_heads",
+           "n_kv_heads": "num_key_value_heads", "d_ff": "intermediate_size",
+           "tie_embeddings": "tie_word_embeddings",
+           "qkv_bias": "attention_bias", "rope_theta": "rope_theta"}
+
+
+def arch(cfg: dict):
+    """The ``ArchConfig`` the engine must serve for ``cfg``: the program's
+    ``deployment.arch`` at the file's depth and vocabulary.  Raises where
+    any other width the file states is not the program's."""
+    import dataclasses
+
+    from repro.configs import get_config
+
+    base = get_config(cfg["deployment"]["arch"])
+    off = [(f, k) for f, k in _WIDTHS.items() if getattr(base, f) != cfg[k]]
+    if off:
+        has = ", ".join(f"{f}={getattr(base, f)!r}" for f, _ in off)
+        states = ", ".join(f"{k}={cfg[k]!r}" for _, k in off)
+        raise ValueError(f"{base.name} has {has}; the configuration states "
+                         f"{states}")
+    return dataclasses.replace(base, n_layers=cfg["num_hidden_layers"],
+                               vocab_size=cfg["vocab_size"])
 
 
 def key_for(seed: int):

@@ -1,6 +1,23 @@
 """Operations and bytes of a dense decoder (qwen2 family), from the
 configuration's published shapes (hf ``config.json`` keys).
 
+A configuration names its counts module under its ``counts`` key; the
+per-layer readers (``metrics/prefill_roofline.py``,
+``metrics/decode_roofline.py``, ``metrics/serve_mfu.py``) load it from
+there.  Every counts module offers these five functions, with these
+signatures (``cfg`` is the configuration's dict):
+
+* ``prefill_flops(cfg, batch, prompt_len)`` and
+  ``prefill_bytes(cfg, batch, prompt_len)``: one prefill call of
+  ``batch`` rows of ``prompt_len`` tokens;
+* ``decode_flops(cfg, batch, context)`` and
+  ``decode_bytes(cfg, batch, context)``: one decode step of ``batch``
+  rows, each attending over ``context`` positions;
+* ``job_contexts(prompt_len, gen_tokens)``: the context of each decode
+  step of a job.
+
+This module counts:
+
 * parameters: per layer q/k/v/o projections (q/k/v biases where
   ``attention_bias`` says so), the gated MLP and two RMSNorm scales; the embedding (tied output head) and the
   final norm;

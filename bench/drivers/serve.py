@@ -17,6 +17,7 @@ reference: the mean sojourn of every split, and the split chosen.
 
 from __future__ import annotations
 
+import dataclasses
 import math
 import time
 
@@ -53,17 +54,18 @@ def _engine_config(cfg: dict, tr: dict, engine_seed: int):
     )
 
 
-def _assert_shapes(engine, cfg: dict) -> None:
-    """The engine must serve the model the configuration file states."""
-    c = engine.cfg
-    got = (c.n_layers, c.d_model, c.n_heads, c.n_kv_heads, c.d_ff,
-           c.vocab_size, c.tie_embeddings, c.qkv_bias)
-    want = (cfg["num_hidden_layers"], cfg["hidden_size"],
-            cfg["num_attention_heads"], cfg["num_key_value_heads"],
-            cfg["intermediate_size"], cfg["vocab_size"],
-            cfg["tie_word_embeddings"], cfg["attention_bias"])
-    if got != want:
-        raise ValueError(f"engine serves {got}, configuration states {want}")
+def _assert_shapes(engine, want) -> None:
+    """The engine must serve the model the configuration file states:
+    ``want``, its weights module's ``arch``, field by field."""
+    got = engine.cfg
+    off = [f.name for f in dataclasses.fields(want)
+           if getattr(got, f.name) != getattr(want, f.name)]
+    if off:
+        def show(c):
+            return ", ".join(f"{n}={getattr(c, n)!r}" for n in off)
+
+        raise ValueError(f"engine serves {show(got)}, configuration states "
+                         f"{show(want)}")
 
 
 def _planned_engine(st, sc):
@@ -97,8 +99,8 @@ def setup(run):
     st.cfg, st.tr = cfg, tr
     st.engine_seed = sub_seed(run.seed, SEED_TAG_ENGINE)
     engine = _planned_engine(st, _engine_config(cfg, tr, st.engine_seed))
-    _assert_shapes(engine, cfg)
     weights = spec.load_module(cfg["weights"])
+    _assert_shapes(engine, weights.arch(cfg))
     st.weights = weights.make(sub_seed(run.seed, SEED_TAG_WEIGHTS), cfg)
     engine.params = weights.to_engine(st.weights, cfg)
     st.engine = engine

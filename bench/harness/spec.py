@@ -3,8 +3,12 @@
 The checkout root holds ``BENCHMARK.json``; everything else of the
 benchmark sits under ``bench/``:
 
-* ``configs/<config>.json``  the configuration as it is run (its
-  ``reference`` key names the plain reference beside it);
+* ``configs/<config>.json``  the configuration as it is run; its keys
+  name what belongs to it alone: ``reference`` (the plain reference
+  beside it), ``weights`` (its weights from the seed, their layout in the
+  program, and ``arch``, the model the engine must serve), ``counts``
+  (its operations and bytes, read by the roofline and MFU metrics) and
+  ``tiny`` (its CPU sizes, for the tests);
 * ``traffic/<traffic>.json`` the traffic mix: its ``driver`` key names
   ``drivers/<driver>.py``, the rest are the driver's parameters;
 * ``metrics/<metric>.py``    one reader per metric, ``read(run)``.

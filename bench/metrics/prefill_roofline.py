@@ -1,11 +1,11 @@
 """Per cent of its roofline a prefill call reached: the least time a
 prefill of the cell's batch and prompt can take, the larger of its model
-FLOPs (``counts/dense_decoder.py``, published shapes, causal attention)
-over the chip's bfloat16 peak and its bytes (every bfloat16 weight read
-once, and the keys and values of the prompt written) over HBM bandwidth,
-divided by the measured device time per prefill call.  Compute binds: a
-prefill of 8 x 1,024 tokens does about 8,000 operations per weight byte,
-against the chip's 240."""
+FLOPs (the configuration's counts module, its ``counts`` key: published
+shapes, causal attention) over the chip's bfloat16 peak and its bytes
+(every bfloat16 weight read once, and the keys and values of the prompt
+written) over HBM bandwidth, divided by the measured device time per
+prefill call.  Compute binds: a prefill of 8 x 1,024 tokens does about
+8,000 operations per weight byte, against the chip's 240."""
 
 from harness import spec, tracing
 
@@ -18,8 +18,8 @@ def read(run):
     _, durs = tracing.program_by_calls(tr, n, exclude=(decode,))
     if not durs:
         return None
-    counts = spec.load_module("counts/dense_decoder.py")
     f, peaks = run.facts, run.peaks
+    counts = spec.load_module(f["model"]["counts"])
     flops = counts.prefill_flops(f["model"], f["batch"], f["prompt_len"])
     nbytes = counts.prefill_bytes(f["model"], f["batch"], f["prompt_len"])
     least = max(flops / peaks["bf16_flops_per_s"],

@@ -15,6 +15,7 @@ import tiny
 
 BENCHMARK = json.load(open(os.path.join(spec.ROOT, "BENCHMARK.json")))
 CELLS = [w["name"] for w in BENCHMARK["workloads"]]
+CONFIGS = {c["name"]: c["file"] for c in BENCHMARK["configs"]}
 
 
 @pytest.mark.parametrize("name", CELLS)
@@ -50,12 +51,28 @@ def test_cell_runs_at_a_tiny_size(monkeypatch, name, traced):
         assert set(res["breakdown"]) == {"device_ops", "idle_gaps"}
 
 
+# what each configuration's file names, by key, and what each must offer
+PER_CONFIG = {
+    "counts": ("prefill_flops", "prefill_bytes", "decode_flops",
+               "decode_bytes", "job_contexts"),
+    "weights": ("make", "arch", "to_engine"),
+    "reference": ("logits", "control_weights", "served_gaps"),
+}
+
+
 def test_every_metric_and_file_is_found_by_name():
     for m in BENCHMARK["end_to_end"] + BENCHMARK["per_layer"]:
         assert hasattr(spec.load_module(f"metrics/{m['name']}.py"), "read")
     for c in BENCHMARK["configs"]:
         cfg = json.load(open(os.path.join(spec.ROOT, c["file"])))
         assert cfg["name"] == c["name"] and cfg["reduced"] == c["reduced"]
+        for key, names in PER_CONFIG.items():
+            assert key in cfg, f"{c['name']}: no {key!r} module"
+            mod = spec.load_module(cfg[key])
+            lacks = [n for n in names if not callable(getattr(mod, n, None))]
+            assert not lacks, f"{c['name']}: {cfg[key]} lacks {lacks}"
+        assert set(cfg.get("tiny", {})) >= {"config", "traffic"}, (
+            f"{c['name']}: no 'tiny' block of CPU sizes")
     for name in CELLS:
         cell = spec.load_cell(name)
         spec.load_module(f"drivers/{cell.traffic['driver']}.py")
@@ -85,14 +102,16 @@ def _sds(shape, dtype, sharding):
     return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
 
 
-def test_plan_sweep_compiles_for_v5e(one_chip):
+@pytest.mark.parametrize("config", sorted(CONFIGS))
+def test_plan_sweep_compiles_for_v5e(one_chip, config):
     """The served engine's initial-plan sweep at its dispatch shapes: one
     cell per split of the replica groups, no straggler policy."""
     import jax.numpy as jnp
 
     from repro.kernels.sojourn_sweep import kernel
 
-    dep = spec.load_json("configs/qwen2-0.5b.json")["deployment"]
+    with open(os.path.join(spec.ROOT, CONFIGS[config])) as f:
+        dep = json.load(f)["deployment"]
     n, j = dep["n_server_groups"], dep["plan_trials"]
     f32, i32 = jnp.float32, jnp.int32
     for g in (b for b in range(1, n + 1) if n % b == 0):
@@ -110,13 +129,14 @@ def test_serving_programs_compile_for_v5e(one_chip, name):
     import jax
     import jax.numpy as jnp
 
-    from repro.configs import get_config
     from repro.models import Shard, decode_step, init_params, prefill
     from repro.models.lm import decode_state_shapes
 
     cell = spec.load_cell(name)
     cfg, tr = cell.config, cell.traffic
-    arch = get_config(cfg["deployment"]["arch"])
+    ref = spec.load_module(cfg["reference"])
+    weights = spec.load_module(cfg["weights"])
+    arch = weights.arch(cfg)
     on_chip = lambda t: jax.tree.map(
         lambda a: _sds(a.shape, a.dtype, one_chip), t)
     params = on_chip(jax.eval_shape(
@@ -129,14 +149,9 @@ def test_serving_programs_compile_for_v5e(one_chip, name):
     jax.jit(lambda p, st, t, c: decode_step(arch, shard, p, st, t, c)).lower(
         params, state, _sds((b, 1), jnp.int32, one_chip),
         _sds((), jnp.int32, one_chip)).compile()
-    ref = spec.load_module("configs/qwen2_0_5b_reference.py")
-    weights = spec.load_module("configs/qwen2_0_5b_weights.py")
     w = on_chip(jax.eval_shape(lambda: weights.make(0, cfg)))
     n, first = tr["check_block"], s - 1
-    fn = ref._forward_fn(
-        (("d", 896), ("H", 14), ("KV", 2), ("hd", 64),
-         ("eps", cfg["rms_norm_eps"]), ("theta", cfg["rope_theta"])), first)
-    compiled = fn.lower(w, _sds((n, s + tr["gen_tokens"] - 1), jnp.int32,
-                                one_chip)).compile()
+    compiled = jax.jit(lambda w, t: ref.logits(w, t, cfg, first)).lower(
+        w, _sds((n, s + tr["gen_tokens"] - 1), jnp.int32, one_chip)).compile()
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 8e9

@@ -1,29 +1,32 @@
-"""Tiny overrides of each configuration and traffic mix, for CPU runs."""
+"""Tiny sizes of a cell, for CPU runs: the ``tiny`` block of the cell's
+configuration file (``{"config": {...}, "traffic": {...}}``), overrides of
+the configuration and of the traffic mix."""
 
-# the published widths at 4 layers and a 16,384-token vocabulary: a model
-# small enough for the CPU whose logits spread like the full model's, so
-# that the cell's limit means the same here
-QWEN = dict(
-    config={"num_hidden_layers": 4, "vocab_size": 16384},
-    traffic={"batch_size": 2, "prompt_len": 16, "gen_tokens": 8,
-             "max_len": 32, "requests_per_call": 4, "check_requests": 4,
-             "check_block": 3})
+from harness import spec
 
 
-def overrides(cell_name: str) -> dict:
-    return QWEN
+def _cell(cell) -> spec.Cell:
+    """A cell, given as one or by its name in ``BENCHMARK.json``."""
+    return spec.load_cell(cell) if isinstance(cell, str) else cell
 
 
-def serve_this_model(monkeypatch, cell_name: str) -> None:
-    """Make the engine serve the tiny model the overrides describe (the
-    engine offers only the published model or its own reduced twin)."""
-    import dataclasses
+def overrides(cell) -> dict:
+    """``Cell.replace`` arguments that cut ``cell`` to its tiny size."""
+    cfg = _cell(cell).config
+    if "tiny" not in cfg:
+        raise KeyError(f"configuration {cfg['name']!r} has no 'tiny' block "
+                       "of CPU sizes (config and traffic overrides)")
+    return {"config": cfg["tiny"]["config"],
+            "traffic": cfg["tiny"]["traffic"]}
 
-    from repro.configs import get_config
+
+def serve_this_model(monkeypatch, cell) -> None:
+    """Make the engine serve the tiny model the overrides describe, its
+    weights module's ``arch`` (the engine offers only the published model
+    or its own reduced twin)."""
     from repro.serving import ServeEngineConfig
 
-    cfg = QWEN["config"]
-    arch = dataclasses.replace(get_config("qwen2-0.5b"),
-                               n_layers=cfg["num_hidden_layers"],
-                               vocab_size=cfg["vocab_size"])
+    small = _cell(cell)
+    small = small.replace(**overrides(small))
+    arch = spec.load_module(small.config["weights"]).arch(small.config)
     monkeypatch.setattr(ServeEngineConfig, "arch_config", lambda self: arch)
