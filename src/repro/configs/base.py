@@ -13,6 +13,8 @@ from typing import Literal, Optional
 
 __all__ = [
     "MoEConfig",
+    "MLAConfig",
+    "YarnConfig",
     "SSMConfig",
     "HybridConfig",
     "ArchConfig",
@@ -34,6 +36,45 @@ class MoEConfig:
     capacity_factor: float = 1.25
     aux_loss_weight: float = 0.01
     first_layer_dense: bool = False  # DeepSeekMoE: layer 0 stays dense
+    # top-k gate weights divided by their sum (norm_topk_prob)
+    renormalize: bool = True
+    # every routed token is computed (grouped matmuls over the held
+    # experts); False: sort-and-capacity dispatch, which drops overflow
+    dropless: bool = False
+    # expert parallelism: this chip holds the first n_held of the router's
+    # n_experts (rank 0's share) and computes their part of the layer
+    # only; 0 holds all of them.  Dropless layers only.
+    n_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """How many experts this chip holds."""
+        return self.n_held or self.n_experts
+
+
+@dataclasses.dataclass(frozen=True)
+class MLAConfig:
+    """Multi-head latent attention (DeepSeek-V2): keys and values come up
+    from one ``kv_lora_rank`` latent per position (RMS-normed), beside one
+    rope key of ``qk_rope_head_dim`` shared by all heads; the query is a
+    plain projection (no ``q_lora_rank``)."""
+
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+
+
+@dataclasses.dataclass(frozen=True)
+class YarnConfig:
+    """YaRN rope scaling (hf ``rope_scaling`` of type ``yarn``)."""
+
+    factor: float
+    original_max_position_embeddings: int
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 1.0
+    mscale_all_dim: float = 0.0
 
 
 @dataclasses.dataclass(frozen=True)
@@ -74,10 +115,12 @@ class ArchConfig:
     norm: Literal["rmsnorm", "layernorm"] = "rmsnorm"
     activation: Literal["swiglu", "gelu"] = "swiglu"
     rope_theta: float = 10_000.0
+    rope_scaling: Optional[YarnConfig] = None
     use_rope: bool = True
     tie_embeddings: bool = False
     logit_softcap: float = 0.0
     moe: Optional[MoEConfig] = None
+    mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     hybrid: Optional[HybridConfig] = None
     # enc-dec (whisper): n_layers counts EACH stack (24 enc + 24 dec)
@@ -99,6 +142,16 @@ class ArchConfig:
             raise ValueError(f"{self.name}: n_heads % n_kv_heads != 0")
         if self.d_model % self.n_heads:
             raise ValueError(f"{self.name}: d_model % n_heads != 0")
+        moe = self.moe
+        if moe is not None and moe.n_held and not moe.dropless:
+            raise ValueError(f"{self.name}: held experts need a dropless "
+                             "layer")
+        if moe is not None and moe.held > moe.n_experts:
+            raise ValueError(f"{self.name}: held experts beyond the router")
+        if self.mla is not None and (self.family != "moe"
+                                     or not moe.first_layer_dense):
+            raise ValueError(f"{self.name}: latent attention is implemented "
+                             "for MoE stacks with a dense layer 0")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -125,6 +178,8 @@ ARCH_IDS = (
     "xlstm-350m",
     "olmoe-1b-7b",
     "deepseek-moe-16b",
+    "deepseek-v2-lite",
+    "deepseek-v2-lite-ep8",
     "zamba2-7b",
     "whisper-medium",
 )
@@ -183,9 +238,16 @@ def reduced_config(cfg: ArchConfig) -> ArchConfig:
     d_model = 64 * heads if cfg.family != "ssm" else 128
     moe = None
     if cfg.moe is not None:
+        # held experts keep their share of the router (at least one)
+        held = max(1, cfg.moe.n_held * 8 // cfg.moe.n_experts)
         moe = dataclasses.replace(
-            cfg.moe, n_experts=8, top_k=min(cfg.moe.top_k, 2), d_expert=64
+            cfg.moe, n_experts=8, top_k=min(cfg.moe.top_k, 2), d_expert=64,
+            n_held=held if cfg.moe.n_held else 0,
         )
+    mla = None
+    if cfg.mla is not None:
+        mla = MLAConfig(kv_lora_rank=32, qk_nope_head_dim=16,
+                        qk_rope_head_dim=8, v_head_dim=16)
     ssm = None
     if cfg.ssm is not None:
         # keep one sLSTM segment end if the original had any (layout: 3m+1s)
@@ -206,6 +268,7 @@ def reduced_config(cfg: ArchConfig) -> ArchConfig:
         d_ff=0 if cfg.d_ff == 0 else 4 * d_model,
         vocab_size=512,
         moe=moe,
+        mla=mla,
         ssm=ssm,
         hybrid=hybrid,
         frontend_dim=32 if cfg.frontend != "none" else 0,

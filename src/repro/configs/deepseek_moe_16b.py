@@ -3,9 +3,10 @@ first layer dense.
 
 [arXiv:2401.06066; hf:deepseek-ai/deepseek-moe-16b-base] 28L d_model=2048
 16H (MHA) d_ff=1408(per expert) vocab=102400, MoE 64e top-6 + 2 shared.
-Dense first-layer FFN width = 2 shared + 6 routed equivalents ~ 10944; we use
-8 * d_expert = 11264 (8 expert-equivalents) for the dense layer, matching the
-activated-expert budget.
+
+Departure: the published dense layer-0 FFN width is 10,944
+(``intermediate_size``); this config uses 8 * d_expert = 11,264 (8
+expert-equivalents) instead.
 """
 
 from repro.configs.base import ArchConfig, MoEConfig
@@ -17,7 +18,7 @@ CONFIG = ArchConfig(
     d_model=2048,
     n_heads=16,
     n_kv_heads=16,
-    d_ff=11264,  # dense layer-0 FFN width (8 expert-equivalents)
+    d_ff=11264,  # dense layer 0; published 10,944 (see above)
     vocab_size=102400,
     norm="rmsnorm",
     activation="swiglu",

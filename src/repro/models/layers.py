@@ -13,13 +13,14 @@ Design rules:
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.configs.base import ArchConfig, ShardingPolicy
+from repro.configs.base import ArchConfig, ShardingPolicy, YarnConfig
 
 DTYPE = jnp.bfloat16
 
@@ -73,17 +74,55 @@ def apply_norm(cfg: ArchConfig, params, x):
 # rotary embedding
 # ---------------------------------------------------------------------------
 
-def rope_freqs(head_dim: int, theta: float) -> jax.Array:
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's magnitude factor (hf modeling_deepseek ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
+def yarn_attention_factor(yarn: Optional[YarnConfig]) -> float:
+    """What YaRN multiplies the softmax scale by: mscale(mscale_all_dim)
+    squared, or 1."""
+    if yarn is None or not yarn.mscale_all_dim:
+        return 1.0
+    return yarn_mscale(yarn.factor, yarn.mscale_all_dim) ** 2
+
+
+def rope_freqs(head_dim: int, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jax.Array:
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2, dtype=jnp.float32) / head_dim))
+    if yarn is None:
+        return freqs
+    # YaRN: the frequencies that turn fewer than beta_fast times over the
+    # original context are divided by ``factor``; a linear ramp between the
+    # correction dims of beta_fast and beta_slow blends the two
+    def correction_dim(rotations):
+        return (head_dim * math.log(yarn.original_max_position_embeddings
+                                    / (rotations * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(correction_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(correction_dim(yarn.beta_slow)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip(
+        (jnp.arange(head_dim // 2, dtype=jnp.float32) - low) / (high - low),
+        0.0, 1.0)
+    return freqs / yarn.factor * ramp + freqs * (1.0 - ramp)
+
+
+def apply_rope(x: jax.Array, positions: jax.Array, theta: float,
+               yarn: Optional[YarnConfig] = None) -> jax.Array:
     """x: (..., seq, heads, head_dim); positions: (..., seq)."""
     head_dim = x.shape[-1]
-    freqs = rope_freqs(head_dim, theta)  # (hd/2,)
+    freqs = rope_freqs(head_dim, theta, yarn)  # (hd/2,)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., s, hd/2)
     cos = jnp.cos(angles)[..., None, :]  # (..., s, 1, hd/2)
     sin = jnp.sin(angles)[..., None, :]
+    if yarn is not None:
+        m = (yarn_mscale(yarn.factor, yarn.mscale)
+             / yarn_mscale(yarn.factor, yarn.mscale_all_dim))
+        if m != 1.0:
+            cos, sin = cos * m, sin * m
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
@@ -183,8 +222,8 @@ def qkv_project(cfg: ArchConfig, params, x, positions=None, shard=None):
         k = k + params["bk"]
         v = v + params["bv"]
     if cfg.use_rope and positions is not None:
-        q = apply_rope(q, positions, cfg.rope_theta)
-        k = apply_rope(k, positions, cfg.rope_theta)
+        q = apply_rope(q, positions, cfg.rope_theta, cfg.rope_scaling)
+        k = apply_rope(k, positions, cfg.rope_theta, cfg.rope_scaling)
     if shard is not None:
         q = shard.heads(q)
     return q, k, v

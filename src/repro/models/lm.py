@@ -23,6 +23,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import ArchConfig, ShardingPolicy
 from repro.models import layers as L
+from repro.models import mla as MLA
 from repro.models import moe as M
 from repro.models import ssm as SSM
 from repro.models import transformer as T
@@ -87,7 +88,9 @@ def init_params(key, cfg: ArchConfig):
             ).astype(L.DTYPE)
         }
 
-    if cfg.family in ("dense", "vlm"):
+    if cfg.mla is not None:
+        p.update(MLA.init_stack(kb, cfg))
+    elif cfg.family in ("dense", "vlm"):
         keys = jax.random.split(kb, cfg.n_layers)
         p["blocks"] = jax.vmap(lambda k: T.init_block(k, cfg))(keys)
     elif cfg.family == "moe":
@@ -137,7 +140,9 @@ def param_specs(cfg: ArchConfig, policy: ShardingPolicy):
     dp = policy.dp_axes if policy.fsdp else None
     if cfg.family == "vlm":
         p["projector"] = {"w": P(None, dp)}
-    if cfg.family in ("dense", "vlm"):
+    if cfg.mla is not None:
+        p.update(MLA.stack_specs(cfg, policy))
+    elif cfg.family in ("dense", "vlm"):
         p["blocks"] = stack(T.block_specs(cfg, policy))
     elif cfg.family == "moe":
         mspec = {
@@ -195,7 +200,10 @@ def _backbone(cfg: ArchConfig, shard: Shard, params, x, positions):
     ckpt = lambda f: jax.checkpoint(
         f, policy=jax.checkpoint_policies.nothing_saveable
     )
-    if cfg.family in ("dense", "vlm"):
+    if cfg.mla is not None:
+        x, _, aux = MLA.forward(cfg, shard, params, x, positions,
+                                keep_cache=False, wrap=ckpt)
+    elif cfg.family in ("dense", "vlm"):
 
         def body(h, lp):
             return T.apply_block(cfg, shard, lp, h, positions), None
@@ -285,6 +293,9 @@ def decode_state_shapes(cfg: ArchConfig, batch: int, max_len: int):
     """ShapeDtypeStruct pytree of the decode state (dry-run friendly)."""
     sds = jax.ShapeDtypeStruct
     kv, hd, ld = cfg.n_kv_heads, cfg.head_dim, cfg.n_layers
+    if cfg.mla is not None:
+        # one latent and one rope key per position and layer
+        return {"kv": sds(MLA.cache_shape(cfg, batch, max_len), L.DTYPE)}
     if cfg.family in ("dense", "vlm", "moe"):
         n_cached = ld
         return {
@@ -344,6 +355,8 @@ def decode_state_specs(cfg: ArchConfig, policy: ShardingPolicy,
         kv_spec = P(None, dp, None, m, None)
     else:
         kv_spec = P(None, dp, None, None, None)
+    if cfg.mla is not None:
+        return {"kv": P(None, dp, None, None)}
     if cfg.family in ("dense", "vlm", "moe"):
         return {"k": kv_spec, "v": kv_spec}
     if cfg.family == "ssm":
@@ -405,7 +418,11 @@ def decode_step(cfg: ArchConfig, shard: Shard, params, state, token,
         )
     x = L.embed_tokens(params["embed"], token)
     positions = cache_len + jnp.zeros((1,), jnp.int32)
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.mla is not None:
+        x, kv = MLA.decode(cfg, shard, params, x, state["kv"], cache_len,
+                           positions)
+        state = {"kv": kv}
+    elif cfg.family in ("dense", "vlm", "moe"):
 
         def body(h, xs):
             if cfg.family == "moe":
@@ -515,7 +532,13 @@ def prefill(cfg: ArchConfig, shard: Shard, params, batch, max_len: int):
     """
     x, positions, _ = _embed_inputs(cfg, shard, params, batch)
     b, s, _ = x.shape
-    if cfg.family in ("dense", "vlm", "moe"):
+    if cfg.mla is not None:
+        x, kv, _ = MLA.forward(cfg, shard, params, x, positions,
+                               keep_cache=True)
+        pad = [(0, 0)] * kv.ndim
+        pad[2] = (0, max_len - s)
+        state = {"kv": jnp.pad(kv.astype(L.DTYPE), pad)}
+    elif cfg.family in ("dense", "vlm", "moe"):
         state = init_decode_state(cfg, b, max_len)
 
         def body(h, xs):
@@ -617,7 +640,9 @@ def count_params(cfg: ArchConfig) -> int:
 
 
 def active_params(cfg: ArchConfig) -> int:
-    """Active parameters per token (MoE: only top_k + shared experts)."""
+    """Active parameters per token (MoE: only top_k + shared experts; where
+    the chip holds some of the router's experts, the expected top_k x held
+    / n_experts of them)."""
     total = count_params(cfg)
     if cfg.moe is None:
         return total
@@ -625,5 +650,6 @@ def active_params(cfg: ArchConfig) -> int:
     d = cfg.d_model
     per_expert = 3 * d * moe.d_expert
     n_moe_layers = cfg.n_layers - (1 if moe.first_layer_dense else 0)
-    inactive = n_moe_layers * (moe.n_experts - moe.top_k) * per_expert
-    return total - inactive
+    active = moe.top_k * moe.held / moe.n_experts
+    inactive = n_moe_layers * (moe.held - active) * per_expert
+    return total - round(inactive)

@@ -1,4 +1,16 @@
-"""Mixture-of-Experts FFN (olmoe-1b-7b, deepseek-moe-16b).
+"""Mixture-of-Experts FFN (olmoe-1b-7b, deepseek-moe-16b, deepseek-v2-lite).
+
+Two dispatches, chosen by ``MoEConfig.dropless``.
+
+Dropless, over the experts this chip holds (deepseek-v2-lite; the first
+``n_held`` of the router's): a float32 softmax over all the router's
+experts and a greedy top-k; the (token,
+expert) pairs whose expert is held are sorted by expert to the front, and
+three grouped matmuls (``jax.lax.ragged_dot``) compute a SwiGLU for each of
+them, however many land on one expert.  Pairs routed to experts held
+elsewhere form no group and are not computed: the layer passes on the part
+of the result that its own experts give, as an expert-parallel rank does
+before the exchange between chips (which one chip does not run).
 
 Sort-based capacity dispatch (MegaBlocks/MaxText style) — never materializes
 the (T, E, C) one-hot of GShard:
@@ -43,11 +55,12 @@ def init_moe(key, cfg: ArchConfig):
     d, f, e = cfg.d_model, moe.d_expert, moe.n_experts
     kg, k1, k2, k3, ks = jax.random.split(key, 5)
     scale_in, scale_out = d ** -0.5, f ** -0.5
+    h = moe.held
     p = {
         "router": (jax.random.normal(kg, (d, e)) * scale_in).astype(jnp.float32),
-        "wi_gate": (jax.random.normal(k1, (e, d, f)) * scale_in).astype(L.DTYPE),
-        "wi_up": (jax.random.normal(k2, (e, d, f)) * scale_in).astype(L.DTYPE),
-        "wo": (jax.random.normal(k3, (e, f, d)) * scale_out).astype(L.DTYPE),
+        "wi_gate": (jax.random.normal(k1, (h, d, f)) * scale_in).astype(L.DTYPE),
+        "wi_up": (jax.random.normal(k2, (h, d, f)) * scale_in).astype(L.DTYPE),
+        "wo": (jax.random.normal(k3, (h, f, d)) * scale_out).astype(L.DTYPE),
     }
     if moe.n_shared > 0:
         p["shared"] = L.init_mlp(ks, cfg, d_ff=moe.n_shared * moe.d_expert)
@@ -77,6 +90,69 @@ def _expert_ffn(params, xb):
     return jnp.einsum("gecf,efd->gecd", h, params["wo"])
 
 
+def _route(moe: MoEConfig, xt, router):
+    """float32 softmax over the router's experts and its greedy top-k:
+    (probs (..., E), gate weights (..., k), expert ids (..., k))."""
+    logits = jnp.einsum(
+        "...d,de->...e", xt.astype(jnp.float32), router.astype(jnp.float32)
+    )
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate_w, gate_e = jax.lax.top_k(probs, moe.top_k)
+    if moe.renormalize:
+        gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
+    return probs, gate_w, gate_e
+
+
+def _aux_loss(moe: MoEConfig, probs, gate_e):
+    """Switch load balancing: E * sum_e f_e * p_e over every token."""
+    e = moe.n_experts
+    me = probs.reshape(-1, e).mean(axis=0)
+    counts = jnp.zeros((e,), jnp.float32).at[gate_e.reshape(-1)].add(1.0)
+    fe = counts / gate_e.size
+    return moe.aux_loss_weight * e * jnp.sum(fe * me)
+
+
+def _held_experts(moe: MoEConfig, params, xt, gate_w, gate_e):
+    """xt (t, d); gate (t, k) -> (t, d) float32: for every (token, expert)
+    pair whose expert this chip holds, the gate weight times the expert's
+    SwiGLU of the token, summed per token.  Nothing is dropped."""
+    t, d = xt.shape
+    held = moe.held
+    ids = gate_e.reshape(-1)
+    mine = ids < held
+    # held pairs sorted by expert to the front; the rest form no group
+    group = jnp.minimum(ids, held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    rows = order // moe.top_k  # the token of each sorted pair
+    xs = xt[rows]
+    g = jax.lax.ragged_dot(xs, params["wi_gate"], sizes)
+    u = jax.lax.ragged_dot(xs, params["wi_up"], sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    y = jax.lax.ragged_dot(h, params["wo"], sizes)
+    # rows in no group are left undefined by the grouped matmul: select
+    w = gate_w.reshape(-1)[order]
+    y = jnp.where(mine[order][:, None], y.astype(jnp.float32) * w[:, None],
+                  0.0)
+    return jnp.zeros((t, d), jnp.float32).at[rows].add(y)
+
+
+def _apply_dropless(cfg: ArchConfig, params, x):
+    moe = cfg.moe
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    with jax.named_scope("moe.route"):
+        probs, gate_w, gate_e = _route(moe, xt, params["router"])
+        aux = _aux_loss(moe, probs, gate_e)
+    with jax.named_scope("moe.experts"):
+        y = _held_experts(moe, params, xt, gate_w, gate_e)
+    y = y.astype(x.dtype).reshape(b, s, d)
+    if moe.n_shared > 0:
+        with jax.named_scope("moe.shared"):
+            y = y + L.apply_mlp(cfg, params["shared"], x)
+    return y, aux
+
+
 def apply_moe(
     cfg: ArchConfig,
     shard: Shard,
@@ -90,8 +166,13 @@ def apply_moe(
     buffers shard (dp, model) so expert compute is fully local — without
     this, capacity slots cannot shard over dp and every device computes the
     global expert load (16x waste; see EXPERIMENTS.md §Perf iteration 1).
+
+    A dropless layer (``MoEConfig.dropless``) has no capacity and no
+    per-shard dispatch: see the module docstring.
     """
     moe = cfg.moe
+    if moe.dropless:
+        return _apply_dropless(cfg, params, x)
     b, s, d = x.shape
     t = b * s
     e, k = moe.n_experts, moe.top_k
@@ -102,18 +183,9 @@ def apply_moe(
     cap = capacity if capacity is not None else router_capacity(moe, tl)
 
     xt = shard.moe_tokens(x.reshape(nd, tl, d))
-    logits = jnp.einsum(
-        "gtd,de->gte", xt.astype(jnp.float32), params["router"]
-    )
-    probs = jax.nn.softmax(logits, axis=-1)  # (D, tl, e)
-    gate_w, gate_e = jax.lax.top_k(probs, k)  # (D, tl, k)
-    gate_w = gate_w / jnp.maximum(gate_w.sum(-1, keepdims=True), 1e-9)
-
-    # -- load-balancing aux loss (Switch): E * sum_e f_e * p_e (global)
-    me = probs.mean(axis=(0, 1))  # (e,)
-    counts = jnp.zeros((e,), jnp.float32).at[gate_e.reshape(-1)].add(1.0)
-    fe = counts / (t * k)
-    aux = moe.aux_loss_weight * e * jnp.sum(fe * me)
+    # probs (D, tl, e); gate weights and ids (D, tl, k)
+    probs, gate_w, gate_e = _route(moe, xt, params["router"])
+    aux = _aux_loss(moe, probs, gate_e)
 
     # -- sort-based dispatch, vectorized over the dp-shard dim
     flat_e = gate_e.reshape(nd, tl * k)

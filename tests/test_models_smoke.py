@@ -102,12 +102,18 @@ def test_param_specs_structure_matches(arch):
 @pytest.mark.parametrize(
     "arch", [a for a in ARCH_IDS if a != "whisper-medium"]
 )
-def test_prefill_decode_matches_forward(arch):
+def test_prefill_decode_matches_forward(monkeypatch, arch):
     cfg = reduced_config(get_config(arch))
     if cfg.moe is not None:  # disable token dropping for exactness
         cfg = dataclasses.replace(
             cfg, moe=dataclasses.replace(cfg.moe, capacity_factor=8.0)
         )
+    if cfg.mla is not None:
+        # the absorbed decode and the expanded forward are the same sums in
+        # another order: compare them in float32, where they agree to
+        # rounding (in bfloat16 they round apart by a few units in the last
+        # place of the logits)
+        monkeypatch.setattr(L, "DTYPE", jnp.float32)
     params = init_params(KEY, cfg)
     shard = Shard.local()
     s = 16
@@ -137,6 +143,9 @@ def test_full_config_param_counts_match_published():
         "granite-34b": (32e9, 36e9),
         "olmoe-1b-7b": (6.5e9, 7.5e9),
         "deepseek-moe-16b": (15.5e9, 17.5e9),
+        "deepseek-v2-lite": (15.6e9, 15.8e9),  # published 15.7B
+        # one EP8 chip's share: 8 of each MoE layer's 64 experts
+        "deepseek-v2-lite-ep8": (3_110_989_312, 3_110_989_312),
         "zamba2-7b": (6.0e9, 7.6e9),
         "internvl2-76b": (68e9, 76e9),  # LM backbone (ViT is stubbed)
         "whisper-medium": (0.7e9, 0.9e9),
@@ -148,7 +157,7 @@ def test_full_config_param_counts_match_published():
 
 
 def test_cell_support_matrix():
-    """32 runnable cells: long_500k only for the sub-quadratic archs."""
+    """38 runnable cells: long_500k only for the sub-quadratic archs."""
     runnable = 0
     for arch in ARCH_IDS:
         cfg = get_config(arch)
@@ -159,4 +168,4 @@ def test_cell_support_matrix():
             else:
                 assert ok
             runnable += ok
-    assert runnable == 32
+    assert runnable == 38
