@@ -423,47 +423,33 @@ def decode_step(cfg: ArchConfig, shard: Shard, params, state, token,
                            positions)
         state = {"kv": kv}
     elif cfg.family in ("dense", "vlm", "moe"):
+        # the stacked caches ride in the scan's carry, each layer writing
+        # its one entry in place: as scan inputs and outputs, every layer's
+        # whole cache would be copied (on a TPU v5e three times each for K
+        # and V, every step)
+        ffn = None
+        if cfg.family == "moe":
+            ffn = lambda lp, h: M.apply_moe(cfg, shard, lp["moe"], h)[0]  # noqa: E731
 
-        def body(h, xs):
-            if cfg.family == "moe":
-                lp, ck, cv = xs
-                h1 = L.apply_norm(cfg, lp["ln1"], h)
-                q, k, v = L.qkv_project(cfg, lp["attn"], h1, positions, shard)
-                ck = jax.lax.dynamic_update_slice_in_dim(
-                    ck, k.astype(ck.dtype), cache_len, axis=1
-                )
-                cv = jax.lax.dynamic_update_slice_in_dim(
-                    cv, v.astype(cv.dtype), cache_len, axis=1
-                )
-                ck, cv = shard.cache(ck), shard.cache(cv)
-                ctx = T.decode_attend(q, ck, cv, cache_len + 1)
-                h = h + L.attn_out(cfg, lp["attn"], ctx, shard)
-                h2 = L.apply_norm(cfg, lp["ln2"], h)
-                y, _ = M.apply_moe(cfg, shard, lp["moe"], h2)
-                return h + y, (ck, cv)
-            lp, ck, cv = xs
-            h, ck, cv = T.apply_block_decode(
-                cfg, shard, lp, h, ck, cv, cache_len, positions
+        def body(carry, lp):
+            h, k, v, slot = carry
+            h, k, v = T.apply_block_decode(
+                cfg, shard, lp, h, k, v, slot, cache_len, positions, ffn
             )
-            return h, (ck, cv)
+            return (h, k, v, slot + 1), None
 
-        blocks = params["blocks"]
+        k, v, slot = state["k"], state["v"], jnp.int32(0)
         if cfg.family == "moe" and cfg.moe.first_layer_dense:
             # dense layer 0 holds cache slot 0
-            h, k0, v0 = T.apply_block_decode(
-                cfg, shard, params["dense_block"], x,
-                state["k"][0], state["v"][0], cache_len, positions,
+            x, k, v = T.apply_block_decode(
+                cfg, shard, params["dense_block"], x, k, v, slot, cache_len,
+                positions,
             )
-            x = h
-            xs = (blocks, state["k"][1:], state["v"][1:])
-            x, (nk, nv) = jax.lax.scan(body, x, xs)
-            new_k = jnp.concatenate([k0[None], nk], axis=0)
-            new_v = jnp.concatenate([v0[None], nv], axis=0)
-        else:
-            x, (new_k, new_v) = jax.lax.scan(
-                body, x, (blocks, state["k"], state["v"])
-            )
-        state = {"k": new_k, "v": new_v}
+            slot = jnp.int32(1)
+        (x, k, v, _), _ = jax.lax.scan(
+            body, (x, k, v, slot), params["blocks"]
+        )
+        state = {"k": k, "v": v}
     elif cfg.family == "ssm":
         n_seg, m_per, trailing = _xlstm_layout(cfg)
         new_state = dict(state)

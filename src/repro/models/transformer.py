@@ -157,30 +157,38 @@ def apply_block_decode(
     x,
     k_cache,
     v_cache,
+    slot,
     cache_len,
     positions,
+    ffn=None,
 ):
     """Single-token decode block.  x: (b, 1, d).
 
-    Writes the new K/V at position cache_len-1... the caller pre-advances:
-    we write at index ``cache_len`` and attend over ``cache_len + 1`` items.
+    ``k_cache``/``v_cache`` are the stacked caches of every layer,
+    (n, b, S_max, kv, hd), carried whole through the caller's layer scan;
+    ``slot`` is this layer's index in them.  The token's K/V is written in
+    place at (slot, cache_len) and the block attends over the slot's
+    ``cache_len + 1`` items, so no layer's cache is sliced out or restacked.
+    ``ffn(params, h)`` is the feed-forward (default: the block's MLP).
     Returns (x_out, k_cache, v_cache).
     """
     h1 = L.apply_norm(cfg, params["ln1"], x)
     q, k, v = L.qkv_project(cfg, params["attn"], h1, positions, shard)
-    k_cache = jax.lax.dynamic_update_slice_in_dim(
-        k_cache, k.astype(k_cache.dtype), cache_len, axis=1
+    at = (slot, 0, cache_len, 0, 0)
+    k_cache = jax.lax.dynamic_update_slice(
+        k_cache, k[None].astype(k_cache.dtype), at
     )
-    v_cache = jax.lax.dynamic_update_slice_in_dim(
-        v_cache, v.astype(v_cache.dtype), cache_len, axis=1
+    v_cache = jax.lax.dynamic_update_slice(
+        v_cache, v[None].astype(v_cache.dtype), at
     )
-    k_cache = shard.cache(k_cache)
-    v_cache = shard.cache(v_cache)
-    ctx = decode_attend(q, k_cache, v_cache, cache_len + 1, cfg.logit_softcap)
+    ck = shard.cache(jax.lax.dynamic_index_in_dim(k_cache, slot, keepdims=False))
+    cv = shard.cache(jax.lax.dynamic_index_in_dim(v_cache, slot, keepdims=False))
+    ctx = decode_attend(q, ck, cv, cache_len + 1, cfg.logit_softcap)
     attn_y = L.attn_out(cfg, params["attn"], ctx, shard)
+    if ffn is None:
+        ffn = lambda p, h: L.apply_mlp(cfg, p["mlp"], h)  # noqa: E731
     if cfg.parallel_block:
-        mlp_y = L.apply_mlp(cfg, params["mlp"], h1)
-        return x + attn_y + mlp_y, k_cache, v_cache
+        return x + attn_y + ffn(params, h1), k_cache, v_cache
     x = x + attn_y
     h2 = L.apply_norm(cfg, params["ln2"], x)
-    return x + L.apply_mlp(cfg, params["mlp"], h2), k_cache, v_cache
+    return x + ffn(params, h2), k_cache, v_cache

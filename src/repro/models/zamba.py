@@ -183,24 +183,22 @@ def apply_zamba_decode(cfg: ArchConfig, shard: Shard, params, x, state,
         )
         return h, new_ssm, new_conv
 
-    def segment(h, xs):
-        seg_params, s_ssm, s_conv, ck, cv = xs
+    # the shared attention's stacked caches ride in the carry, one slot per
+    # segment, written in place (as in lm.decode_step)
+    def segment(carry, xs):
+        h, k, v, slot = carry
+        seg_params, s_ssm, s_conv = xs
         h, new_ssm, new_conv = mamba_steps(h, seg_params, s_ssm, s_conv)
-        h, ck, cv = transformer.apply_block_decode(
-            cfg, shard, params["shared_attn"], h, ck, cv, cache_len, positions
+        h, k, v = transformer.apply_block_decode(
+            cfg, shard, params["shared_attn"], h, k, v, slot, cache_len,
+            positions,
         )
-        return h, (new_ssm, new_conv, ck, cv)
+        return (h, k, v, slot + 1), (new_ssm, new_conv)
 
-    x, (new_ssm, new_conv, new_k, new_v) = jax.lax.scan(
+    (x, new_k, new_v, _), (new_ssm, new_conv) = jax.lax.scan(
         segment,
-        x,
-        (
-            params["mamba_segments"],
-            state["seg_ssm"],
-            state["seg_conv"],
-            state["attn_k"],
-            state["attn_v"],
-        ),
+        (x, state["attn_k"], state["attn_v"], jnp.int32(0)),
+        (params["mamba_segments"], state["seg_ssm"], state["seg_conv"]),
     )
     new_state = dict(state)
     new_state.update(

@@ -11,7 +11,9 @@ only one process may load the TPU library, and every test worker imports
 this file.
 """
 
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -160,3 +162,50 @@ def test_serving_pick_reads_materialised_logits(serving, program):
     assert any("iota(" in b and "reduce(" in b for b in blocks)
     assert not [b.splitlines()[0] for b in blocks
                 if "convolution(" in b and "iota(" in b and "reduce(" in b]
+
+
+# the chat cell's decode: full-width qwen2-0.5b, batch 8, a 1,152-slot cache
+CHAT = dict(batch=8, max_len=1152)
+
+
+def _copies(block, elements):
+    """Names of the copies in one HLO computation whose (first) output
+    buffer holds ``elements`` elements."""
+    out = []
+    for line in block.splitlines():
+        m = re.match(r"\s*(?:ROOT )?%(\S+) = (.+?) (copy|copy-start)\(%", line)
+        if m:
+            dims = re.search(r"\[([\d,]*)\]", m.group(2)).group(1)
+            if math.prod(int(d) for d in dims.split(",") if d) == elements:
+                out.append(m.group(1))
+    return out
+
+
+def test_dense_decode_updates_the_cache_in_place(spec):
+    """The decode step keeps the stacked KV caches in the layer scan's
+    carry: the layer loop copies no layer's cache (restacking them as scan
+    outputs took three copies each of K and V per layer), and the entry
+    makes at most one pass over each whole cache."""
+    from repro.configs import get_config
+    from repro.models import Shard, decode_state_shapes, decode_step, init_params
+
+    cfg = get_config("qwen2-0.5b")
+    b, s = CHAT["batch"], CHAT["max_len"]
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = shapes(decode_state_shapes(cfg, b, s))
+    shard = Shard.local()
+    text = jax.jit(
+        lambda p, st, t, c: decode_step(cfg, shard, p, st, t, c)
+    ).lower(params, state, spec((b, 1), jnp.int32),
+            spec((), jnp.int32)).compile().as_text()
+
+    layer = b * s * cfg.n_kv_heads * cfg.head_dim
+    blocks = text.split("\n\n")
+    entry = [blk for blk in blocks if blk.lstrip().startswith("ENTRY")]
+    assert len(entry) == 1
+    assert not [c for blk in blocks if blk not in entry
+                for c in _copies(blk, layer)]
+    assert len(_copies(entry[0], cfg.n_layers * layer)) <= 2
