@@ -55,13 +55,13 @@ from repro.serving import ReplicatedServingEngine, ServeEngineConfig
 
 
 def _engine_run(
-    dist, n, b, util, seed=42, jobs=6_000, speculation=None,
+    dist, n, b, util, seed=42, jobs=6_000, clone_quantile=None,
     discipline="fifo", deadlines=None,
 ):
     eng = ReplicatedServingEngine(ServeEngineConfig(
         n_server_groups=n, n_batches=b, batch_size=4, prompt_len=16,
         gen_tokens=8, delta=dist.delta, mu=dist.mu, utilization=util,
-        execute_model=False, seed=seed, speculation_quantile=speculation,
+        execute_model=False, seed=seed, speculation_quantile=clone_quantile,
         queue_discipline=discipline,
     ))
     return eng.run_load(n_requests=jobs, deadlines=deadlines)
@@ -120,17 +120,20 @@ def run(n=16, jobs=6_000):
         heavy_spec,
         Objective(
             metric="p99", utilization=0.7,
-            speculation_quantiles=(0.8, 0.9, 0.95),
+            policies=tuple(
+                PolicyCandidate("clone", quantile=q) for q in (0.8, 0.9, 0.95)
+            ),
         ),
     )
-    b_s, q_s = spec_plan.n_batches, spec_plan.speculation_quantile
-    assert q_s is not None, "planner should choose to speculate on this fleet"
+    b_s, pol_s = spec_plan.n_batches, spec_plan.policy
+    assert pol_s.kind == "clone", "planner should choose to clone on this fleet"
+    q_s = pol_s.quantile
     # engine-measured (independent seed): every pure-B split vs the pick
     pure = {
         b: _engine_run(heavy, n, b, 0.7, jobs=jobs)["p99_sojourn"]
         for b in (1, 2, 4, 8, n)
     }
-    spec_run = _engine_run(heavy, n, b_s, 0.7, jobs=jobs, speculation=q_s)
+    spec_run = _engine_run(heavy, n, b_s, 0.7, jobs=jobs, clone_quantile=q_s)
     spec_p99 = spec_run["p99_sojourn"]
     # the headline: late-quantile speculation beats no-speculation at the
     # same B AND every pure-B replication level at equal worker budget

@@ -90,6 +90,7 @@ from repro.core import (
     TunerConfig,
     censored_observations,
     make_planner,
+    straggler_portfolio,
 )
 from repro.core.policies import Assignment
 from repro.distributed.elastic import RescaleExecutor, RuntimeTopology
@@ -468,22 +469,8 @@ class ClusterCoordinator:
             ),
             planner=self.planner,
             job_load=self._work(cfg.batch_size),
-            **(
-                {"policy_candidates": cfg.policy_candidates}
-                if cfg.policy_candidates
-                else (
-                    {"policy_candidates": (self.policy,)}
-                    if self.policy is not None
-                    and self.policy.kind in ("relaunch", "hedged")
-                    else {
-                        "speculation_quantiles": (
-                            (self.policy.quantile,)
-                            if self.policy is not None
-                            and self.policy.kind == "clone"
-                            else None
-                        )
-                    }
-                )
+            policy_candidates=straggler_portfolio(
+                self.policy, cfg.policy_candidates
             ),
         )
 

@@ -46,6 +46,7 @@ from .policies import (
     random_assignment,
     rate_aware_assignment,
     replica_major_nonoverlapping,
+    straggler_portfolio,
     unbalanced_nonoverlapping,
 )
 from .replication import (
@@ -63,7 +64,6 @@ from .simulator import (
     ServingSimResult,
     ServingSweepResult,
     SimResult,
-    SpeculativeSweepResult,
     StepTimeSimulator,
     SweepSimResult,
     censored_observations,
@@ -80,7 +80,6 @@ from .simulator import (
     sweep_sojourn_coded,
     sweep_sojourn_policies,
     sweep_sojourn_serving,
-    sweep_sojourn_speculative,
 )
 from .spectrum import (
     METRICS,

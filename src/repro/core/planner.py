@@ -106,20 +106,19 @@ __all__ = [
 _CLOSED_FORM_MAX_BATCHES = 16
 
 
-def _best_speculative_point(
+def _best_policy_point(
     n_batches: int,
     replication: int,
     sample_sets: Sequence[np.ndarray],
-    quantiles: Sequence[Optional[float]],
+    labels: Sequence[PolicyCandidate],
     metric: Metric,
     feasible: Optional[Sequence[bool]] = None,
-) -> tuple[SpectrumPoint, Optional[float]]:
+) -> tuple[SpectrumPoint, PolicyCandidate]:
     """Pick one B's best candidate: build a SpectrumPoint per candidate
     sample set and return the (point, label) minimizing the objective
-    metric.  Label-generic — ``quantiles`` holds clone triggers on the
-    legacy speculation axis (None = plain replication) and
-    :class:`~repro.core.policies.PolicyCandidate` objects on the policy
-    axis.
+    metric.  ``labels`` are the
+    :class:`~repro.core.policies.PolicyCandidate` objects the sample sets
+    were drawn under.
 
     ``feasible`` masks candidates that fail the stability gate (charged
     utilization >= 1 once the policy's redundant work is accounted): an
@@ -138,7 +137,7 @@ def _best_speculative_point(
         indices,
         key=lambda qi: metric_value(candidates[qi], metric),
     )
-    return candidates[best], quantiles[best]
+    return candidates[best], labels[best]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -287,24 +286,19 @@ class Objective:
     a serving batch is ``max_batch_size`` requests no matter how the fleet
     is factored).  Only simulated planners can score load-aware objectives.
 
-    **Speculative re-dispatch.**  ``speculation_quantiles`` (load-aware
-    objectives only) asks the simulated planners to also score each
-    candidate B WITH a clone-attack trigger at each listed late-quantile —
-    a job whose first response is later than that quantile of its service
-    distribution grabs an idle replica-set for one speculative clone
-    (:func:`~repro.core.simulator.sweep_sojourn_speculative`).  The plan
-    then carries the winning trigger as
-    :attr:`Plan.speculation_quantile` (``None`` when plain replication won).
-
     **Straggler-policy portfolio.**  ``policies`` (load-aware objectives
-    only; mutually exclusive with ``speculation_quantiles``) asks the
-    simulated planners to score each candidate B under each listed
-    :class:`~repro.core.policies.PolicyCandidate` — clone vs relaunch vs
-    hedged vs none, one batched CRN call
+    only) asks the simulated planners to score each candidate B under each
+    listed :class:`~repro.core.policies.PolicyCandidate` — clone vs
+    relaunch vs hedged vs none, one batched CRN call
     (:func:`~repro.core.simulator.sweep_sojourn_policies`) — and the plan
-    carries the winning candidate as :attr:`Plan.policy`.  A ``'none'``
-    baseline is prepended automatically when absent, so "do nothing" always
-    competes.
+    carries the winning candidate as :attr:`Plan.policy`.  A clone trigger
+    at late-quantile ``q`` is ``PolicyCandidate('clone', quantile=q)``: a
+    job whose first response is later than that quantile of its service
+    distribution grabs an idle replica-set for one speculative clone.  A
+    ``'none'`` baseline is prepended automatically when absent, so "do
+    nothing" always competes, and a candidate whose charged utilization
+    (:meth:`charged_utilization`) reaches 1 cannot win while a stable one
+    exists.
 
     **Coded alternatives.**  ``coding`` asks the simulated planners to also
     score each listed :class:`~repro.core.coding.CodingCandidate` — cyclic
@@ -342,7 +336,7 @@ class Objective:
     class-weighted objective metric over served requests, and lands on
     :attr:`Plan.policy` / :attr:`Plan.max_wait` / :attr:`Plan.shed` with a
     per-class miss report in :attr:`Plan.class_report`.  Mutually exclusive
-    with ``speculation_quantiles`` and ``coding``.
+    with ``coding``.
 
     >>> Objective(metric="p99", utilization=0.7).load_aware
     True
@@ -356,7 +350,6 @@ class Objective:
     arrival_rate: Optional[float] = None
     utilization: Optional[float] = None
     job_load: float = 1.0
-    speculation_quantiles: Optional[tuple[float, ...]] = None
     policies: Optional[tuple[PolicyCandidate, ...]] = None
     arrivals: Optional[tuple[float, ...]] = None
     coding: Optional[tuple[CodingCandidate, ...]] = None
@@ -394,34 +387,7 @@ class Objective:
             )
         if not self.job_load > 0:
             raise ValueError(f"job_load must be positive, got {self.job_load}")
-        if self.speculation_quantiles is not None:
-            object.__setattr__(
-                self,
-                "speculation_quantiles",
-                tuple(float(q) for q in self.speculation_quantiles),
-            )
-            if not self.speculation_quantiles:
-                raise ValueError(
-                    "speculation_quantiles must be non-empty when given"
-                )
-            for q in self.speculation_quantiles:
-                if not 0.0 < q < 1.0:
-                    raise ValueError(
-                        f"speculation quantiles must be in (0, 1), got {q}"
-                    )
-            if not self.load_aware:
-                raise ValueError(
-                    "speculation_quantiles needs a load-aware objective "
-                    "(arrival_rate or utilization): speculation is scored "
-                    "on sojourn under queueing"
-                )
         if self.policies is not None:
-            if self.speculation_quantiles is not None:
-                raise ValueError(
-                    "give policies OR speculation_quantiles, not both — a "
-                    "clone trigger is expressed as "
-                    "PolicyCandidate('clone', quantile=q) on the policy axis"
-                )
             pols = tuple(self.policies)
             if not pols:
                 raise ValueError("policies must be non-empty when given")
@@ -492,12 +458,6 @@ class Objective:
                 raise ValueError(
                     "slo_classes needs batch_size (requests per batch-job): "
                     "the serving sweep forms request batches"
-                )
-            if self.speculation_quantiles is not None:
-                raise ValueError(
-                    "slo_classes is incompatible with the legacy "
-                    "speculation_quantiles axis — express clone triggers as "
-                    "PolicyCandidate('clone', quantile=q) in policies"
                 )
             if self.coding is not None:
                 raise ValueError(
@@ -613,16 +573,10 @@ class Objective:
 class Plan:
     """The planner's decision: factoring + placement + predicted metrics.
 
-    ``speculation_quantile`` is the late-quantile clone trigger the planner
-    chose for the emitted B (only when the Objective offered
-    ``speculation_quantiles``); ``None`` means plain replication scored
-    best and the serving engine should not speculate.
-
     ``policy`` is the winning :class:`~repro.core.policies.PolicyCandidate`
     at the emitted B (only when the Objective offered ``policies``); a
     ``kind='none'`` candidate means every intervention lost to plain
-    replication.  When a clone candidate wins, ``speculation_quantile``
-    mirrors its trigger so pre-portfolio consumers keep working.
+    replication and the serving engine should not mitigate.
 
     ``confidence`` and ``vote_share`` are the bootstrap-uncertainty report
     of :class:`EmpiricalPlanner` (None from every other planner):
@@ -645,10 +599,10 @@ class Plan:
     replication won and the rest of the plan reads as before.  When coding
     wins, ``predicted`` carries the coded samples (``n_batches`` reads N:
     every worker holds a distinct coded share, replication factor 1 on the
-    storage axis the replication vocabulary can express), ``policy`` and
-    ``speculation_quantile`` are ``None`` (the code IS the straggler
-    strategy), and ``spectrum`` still describes the replication sweep so
-    hysteresis comparisons keep working.
+    storage axis the replication vocabulary can express), ``policy`` is
+    ``None`` (the code IS the straggler strategy), and ``spectrum`` still
+    describes the replication sweep so hysteresis comparisons keep
+    working.
 
     ``max_wait`` / ``shed`` / ``class_report`` are the serving-sweep
     decision (only when the Objective carried ``slo_classes``): the batch
@@ -666,7 +620,6 @@ class Plan:
     spectrum: SpectrumResult
     planner: str  # name of the Planner that produced this
     closed_form_mean: Optional[float] = None  # hetero closed-form companion
-    speculation_quantile: Optional[float] = None  # chosen clone trigger
     policy: Optional[PolicyCandidate] = None  # chosen straggler policy
     confidence: Optional[float] = None  # bootstrap vote share at B*
     vote_share: Optional[tuple[tuple[int, float], ...]] = None  # per-B votes
@@ -763,11 +716,6 @@ class Planner:
             spec.dist, spec.n_workers, assignment.worker_batch, spec.rates
         )
 
-    def _speculation_for(self, n_batches: int) -> Optional[float]:
-        """The clone trigger chosen for ``n_batches`` by the last sweep
-        (None unless a speculative sweep ran and speculation won there)."""
-        return None
-
     def _policy_for(self, n_batches: int) -> Optional[PolicyCandidate]:
         """The straggler policy chosen for ``n_batches`` by the last sweep
         (None unless the objective carried a policy portfolio)."""
@@ -775,14 +723,8 @@ class Planner:
 
     def _decision_fields(self, n_batches: int) -> dict:
         """Plan fields carrying the per-B sweep decisions: the winning
-        policy candidate and — when a clone candidate won, or the legacy
-        speculation sweep ran — the clone trigger mirror."""
-        pol = self._policy_for(n_batches)
-        if pol is not None:
-            spec_q = pol.quantile if pol.kind == "clone" else None
-        else:
-            spec_q = self._speculation_for(n_batches)
-        return {"policy": pol, "speculation_quantile": spec_q}
+        policy candidate."""
+        return {"policy": self._policy_for(n_batches)}
 
     def _plan_backend(self) -> Optional[str]:
         """Resolved simulation backend of the last sweep (Plan provenance;
@@ -846,7 +788,7 @@ class Planner:
         decisions = (
             self._decision_fields(predicted.n_batches)
             if coding is None
-            else {"policy": None, "speculation_quantile": None}
+            else {"policy": None}
         )
         return Plan(
             spec=spec,
@@ -869,7 +811,7 @@ class AnalyticPlanner(Planner):
     """Closed-form sweep (Thms 2-4): homogeneous Exp/SExp fleets only.
 
     Microsecond re-plans, but no heterogeneous rates and no queueing:
-    load-aware objectives (and therefore speculation) are rejected.
+    load-aware objectives (and therefore straggler policies) are rejected.
 
     >>> spec = ClusterSpec(n_workers=16, dist=Exponential(mu=2.0))
     >>> AnalyticPlanner().plan(spec, Objective(metric="mean")).n_batches
@@ -928,9 +870,6 @@ class SimulatedPlanner(Planner):
 
     def _sweep_rates(self, spec: ClusterSpec) -> Optional[np.ndarray]:
         return None
-
-    def _speculation_for(self, n_batches: int) -> Optional[float]:
-        return getattr(self, "_spec_q_by_b", {}).get(n_batches)
 
     def _policy_for(self, n_batches: int) -> Optional[PolicyCandidate]:
         return getattr(self, "_policy_by_b", {}).get(n_batches)
@@ -1035,12 +974,6 @@ class SimulatedPlanner(Planner):
         (queue wait + service) at the objective's offered load, from ONE
         shared CRN draw matrix + arrival sequence (simulator.sweep_sojourn).
 
-        With ``objective.speculation_quantiles`` the candidates become
-        (B, clone-trigger) pairs — every B is also scored with a speculative
-        clone at each listed late-quantile (plus the no-speculation
-        baseline), each B keeps its best trigger, and the winners are
-        recorded for :attr:`Plan.speculation_quantile`.
-
         With ``objective.policies`` the candidates become (B, policy) pairs
         scored in one :func:`~repro.core.simulator.sweep_sojourn_policies`
         call; each B keeps its best :class:`PolicyCandidate` and the
@@ -1050,7 +983,6 @@ class SimulatedPlanner(Planner):
         from .simulator import (  # local: avoid import cycle
             sweep_sojourn,
             sweep_sojourn_policies,
-            sweep_sojourn_speculative,
         )
 
         backend = self._resolve_backend()
@@ -1077,7 +1009,7 @@ class SimulatedPlanner(Planner):
                 for p in res.policies
             ]
             for i, b in enumerate(res.splits):
-                point, best_p = _best_speculative_point(
+                point, best_p = _best_policy_point(
                     b,
                     spec.n_workers // b,
                     [res.samples[0, i, pi] for pi in range(len(res.policies))],
@@ -1088,35 +1020,6 @@ class SimulatedPlanner(Planner):
                 self._policy_by_b[b] = best_p
                 pts.append(point)
             return result_from_points(pts)
-        if objective.speculation_quantiles:
-            quantiles = (None, *objective.speculation_quantiles)
-            res = sweep_sojourn_speculative(
-                spec.dist,
-                spec.n_workers,
-                arrival_rate=objective.offered_rate(spec),
-                quantiles=quantiles,
-                n_jobs=self.n_trials,
-                seed=self.seed,
-                feasible_b=spec.feasible_batches(),
-                rates=self._sweep_rates(spec),
-                job_load=objective.job_load,
-                arrivals=objective.arrivals,
-                backend=backend,
-            )
-            pts = []
-            self._spec_q_by_b = {}
-            for i, b in enumerate(res.splits):
-                point, best_q = _best_speculative_point(
-                    b,
-                    spec.n_workers // b,
-                    [res.samples[0, i, qi] for qi in range(len(quantiles))],
-                    quantiles,
-                    objective.metric,
-                )
-                self._spec_q_by_b[b] = best_q
-                pts.append(point)
-            return result_from_points(pts)
-        self._spec_q_by_b = {}
         res = sweep_sojourn(
             spec.dist,
             spec.n_workers,
@@ -1137,7 +1040,6 @@ class SimulatedPlanner(Planner):
     def sweep_spectrum(
         self, spec: ClusterSpec, objective: Objective
     ) -> SpectrumResult:
-        self._spec_q_by_b = {}
         self._policy_by_b = {}
         if objective.load_aware:
             return self._sweep_sojourn(spec, objective)
@@ -1249,9 +1151,6 @@ class SimulatedPlanner(Planner):
             predicted=spectrum.at(b_star),
             spectrum=spectrum,
             planner=self.name,
-            speculation_quantile=(
-                pol.quantile if pol.kind == "clone" else None
-            ),
             policy=pol,
             backend=self._plan_backend(),
             max_wait=float(res.max_waits[wi]),
@@ -1282,10 +1181,9 @@ class HeterogeneousPlanner(SimulatedPlanner):
     bit-identical to :class:`SimulatedPlanner` — it takes the identical
     batched-sweep path (``mu * 1.0 == mu`` exactly in the engine) and the
     placement falls back to the same replica-major balanced layout.
-    ``backend`` reaches the homogeneous sweeps and the skewed
-    policy-portfolio path; the skewed legacy-speculation and coverage
-    paths stay numpy (the Plan's ``backend`` field records which engine
-    actually ran).
+    ``backend`` reaches the homogeneous sweeps and the skewed sojourn
+    path; the skewed coverage path stays numpy (the Plan's ``backend``
+    field records which engine actually ran).
 
     >>> skewed = ClusterSpec(n_workers=8, dist=Exponential(mu=2.0),
     ...                      rates=(0.2, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0, 1.0))
@@ -1303,7 +1201,6 @@ class HeterogeneousPlanner(SimulatedPlanner):
     def sweep_spectrum(
         self, spec: ClusterSpec, objective: Objective
     ) -> SpectrumResult:
-        self._spec_q_by_b = {}
         self._policy_by_b = {}
         if not spec.heterogeneous:
             return super().sweep_spectrum(spec, objective)
@@ -1312,77 +1209,45 @@ class HeterogeneousPlanner(SimulatedPlanner):
             # the placement the plan actually emits (rate-aware replica
             # sets); the shared seed keeps the arrival sequence and draw
             # matrix common across B, exactly like the batched sweeps.
-            # speculation_quantiles extends the candidates to (B, trigger)
-            # pairs — all triggers of one B share one draw set
-            # (simulate_sojourn_quantiles), same as the homogeneous sweep;
-            # a policy portfolio rides simulate_sojourn_policies the same
-            # way, one draw set per B shared by every candidate.
+            # Every candidate of one B shares one draw set
+            # (simulate_sojourn_policies); without a portfolio the 'none'
+            # baseline alone is the plain sojourn of the placement.
             from .simulator import (  # local: avoid import cycle
                 simulate_sojourn_policies,
-                simulate_sojourn_quantiles,
             )
 
             rate = objective.offered_rate(spec)
-            if objective.policies:
-                backend = self._resolve_backend()
-                stable = [
-                    objective.charged_utilization(spec, p) < 1.0
-                    for p in objective.policies
-                ]
-                pts = []
-                for b in spec.feasible_batches():
-                    assignment = rate_aware_assignment(
-                        spec.n_workers, b, spec.rates
-                    )
-                    sample_sets = simulate_sojourn_policies(
-                        spec.dist,
-                        spec.n_workers,
-                        b,
-                        arrival_rate=rate,
-                        policies=objective.policies,
-                        n_jobs=self.n_trials,
-                        seed=self.seed,
-                        rates=spec.rates,
-                        job_load=objective.job_load,
-                        worker_batch=assignment.worker_batch,
-                        arrivals=objective.arrivals,
-                        backend=backend,
-                    )
-                    point, best_p = _best_speculative_point(
-                        b, spec.n_workers // b, sample_sets,
-                        objective.policies, objective.metric,
-                        feasible=stable,
-                    )
-                    self._policy_by_b[b] = best_p
-                    pts.append(point)
-                return result_from_points(pts)
-            quantiles: tuple[Optional[float], ...] = (None,)
-            if objective.speculation_quantiles:
-                quantiles = (None, *objective.speculation_quantiles)
-            self._last_backend = "numpy"
+            policies = objective.policies or (PolicyCandidate(),)
+            backend = self._resolve_backend()
+            stable = [
+                objective.charged_utilization(spec, p) < 1.0
+                for p in policies
+            ]
             pts = []
             for b in spec.feasible_batches():
                 assignment = rate_aware_assignment(
                     spec.n_workers, b, spec.rates
                 )
-                sample_sets = simulate_sojourn_quantiles(
+                sample_sets = simulate_sojourn_policies(
                     spec.dist,
                     spec.n_workers,
                     b,
                     arrival_rate=rate,
-                    quantiles=quantiles,
+                    policies=policies,
                     n_jobs=self.n_trials,
                     seed=self.seed,
                     rates=spec.rates,
                     job_load=objective.job_load,
                     worker_batch=assignment.worker_batch,
                     arrivals=objective.arrivals,
+                    backend=backend,
                 )
-                point, best_q = _best_speculative_point(
-                    b, spec.n_workers // b, sample_sets, quantiles,
-                    objective.metric,
+                point, best_p = _best_policy_point(
+                    b, spec.n_workers // b, sample_sets, policies,
+                    objective.metric, feasible=stable,
                 )
-                self._spec_q_by_b[b] = best_q
+                if objective.policies:
+                    self._policy_by_b[b] = best_p
                 pts.append(point)
             return result_from_points(pts)
         from .simulator import simulate_coverage  # local: avoid import cycle
@@ -1421,20 +1286,16 @@ class EmpiricalPlanner(SimulatedPlanner):
     per B (the bootstrap-smoothed estimate).  A parametric ``spec.dist`` is
     accepted for convenience (a ``pool_size`` synthetic pool is drawn from
     it first) — the statistical-recovery tests feed known Exp/SExp fleets
-    through exactly that path.  Load-aware objectives, speculation
-    triggers, and straggler-policy portfolios are supported through the
-    same sojourn sweeps as :class:`SimulatedPlanner`.
+    through exactly that path.  Load-aware objectives and straggler-policy
+    portfolios are supported through the same sojourn sweeps as
+    :class:`SimulatedPlanner`.
 
     **Rate-aware bootstrap.**  Per-worker rate skew composes with the
     empirical path: the engine couples each bootstrap resample to the
     shared draws by rank and divides by the per-worker rate
     (scaled-quantile coupling, :func:`~repro.core.simulator._unit_times`),
     and every candidate B is scored under the rate-aware placement the
-    plan actually emits (``worker_batches`` threading).  The one
-    still-unsupported combination — skewed rates with the LEGACY
-    ``speculation_quantiles`` axis — keeps the loud ``ValueError`` guard:
-    express clone triggers as ``PolicyCandidate('clone', q)`` on the
-    policy axis instead.
+    plan actually emits (``worker_batches`` threading).
 
     >>> import numpy as np
     >>> pool = np.random.default_rng(0).lognormal(0.0, 1.0, 2_000)
@@ -1494,8 +1355,8 @@ class EmpiricalPlanner(SimulatedPlanner):
         """Votes (always, on ``self._votes``) + pooled spectrum from
         per-(resample, B) sample sets.  Each cell is materialized ONCE and
         reused for the pooled points; ``pooled=False`` skips the pooled
-        spectrum for callers that build their own (the speculative sweep,
-        whose spectrum must describe the adopted trigger)."""
+        spectrum for callers that build their own (the policy sweep,
+        whose spectrum must describe the adopted policy)."""
         k_count = self.n_resamples
         cells = [
             [per_cell_samples(k, s) for s in range(len(splits))]
@@ -1535,20 +1396,9 @@ class EmpiricalPlanner(SimulatedPlanner):
             sweep_simulate,
             sweep_sojourn,
             sweep_sojourn_policies,
-            sweep_sojourn_speculative,
         )
 
-        self._spec_q_by_b = {}
         self._policy_by_b = {}
-        if spec.has_skewed_rates and objective.speculation_quantiles:
-            raise ValueError(
-                "EmpiricalPlanner cannot combine a rate-skewed fleet with "
-                "the legacy speculation_quantiles axis — express clone "
-                "triggers as PolicyCandidate('clone', q) entries in "
-                "Objective.policies (the policy axis threads the rate-aware "
-                "placement through the bootstrap sweep), or use "
-                "HeterogeneousPlanner (make_planner('heterogeneous'))."
-            )
         dists = self._bootstrap_dists(spec)
         # cached for the coded race: _bootstrap_dists draws fresh resamples
         # every call, so the coded sweep must reuse THESE dists to stay on
@@ -1633,71 +1483,6 @@ class EmpiricalPlanner(SimulatedPlanner):
                     b,
                     spec.n_workers // b,
                     res.samples[:, s, best_p_index[b], :].ravel(),
-                )
-                for s, b in enumerate(splits)
-            )
-        if objective.load_aware and objective.speculation_quantiles:
-            quantiles = (None, *objective.speculation_quantiles)
-            res = sweep_sojourn_speculative(
-                dists,
-                spec.n_workers,
-                arrival_rate=objective.offered_rate(spec),
-                quantiles=quantiles,
-                n_jobs=self.n_trials,
-                seed=self.seed,
-                feasible_b=splits,
-                job_load=objective.job_load,
-                arrivals=objective.arrivals,
-                backend=backend,
-            )
-            # each resample scores every B at its best trigger; the trigger
-            # REPORTED per B comes from the pooled samples (one consistent
-            # answer for the engine to adopt, not K conflicting ones)
-            best_q_index: dict[int, int] = {}
-            for s, b in enumerate(splits):
-                pooled_pts = [
-                    point_from_samples(
-                        b,
-                        spec.n_workers // b,
-                        res.samples[:, s, qi, :].ravel(),
-                    )
-                    for qi in range(len(quantiles))
-                ]
-                qi_best = min(
-                    range(len(quantiles)),
-                    key=lambda qi: metric_value(
-                        pooled_pts[qi], objective.metric
-                    ),
-                )
-                best_q_index[b] = qi_best
-                self._spec_q_by_b[b] = quantiles[qi_best]
-
-            def cell(k: int, s: int):
-                # per-resample best trigger for voting (a resample votes for
-                # the B it would run, at the trigger it would pick)
-                pts = [
-                    point_from_samples(
-                        splits[s],
-                        spec.n_workers // splits[s],
-                        res.samples[k, s, qi],
-                    )
-                    for qi in range(len(quantiles))
-                ]
-                qi = min(
-                    range(len(quantiles)),
-                    key=lambda i: metric_value(pts[i], objective.metric),
-                )
-                return res.samples[k, s, qi]
-
-            self._reduce_votes(
-                splits, spec.n_workers, cell, objective.metric, pooled=False
-            )
-            # the pooled spectrum must describe the trigger the plan adopts
-            return result_from_points(
-                point_from_samples(
-                    b,
-                    spec.n_workers // b,
-                    res.samples[:, s, best_q_index[b], :].ravel(),
                 )
                 for s, b in enumerate(splits)
             )
@@ -1822,7 +1607,7 @@ class EmpiricalPlanner(SimulatedPlanner):
             decisions = self._decision_fields(best_b)
             confidence = votes.get(best_b, 0) / total
         else:
-            decisions = {"policy": None, "speculation_quantile": None}
+            decisions = {"policy": None}
             # when coding wins, confidence reports the coded-race vote
             confidence = self._coding_votes
         return Plan(

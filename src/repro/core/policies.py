@@ -35,6 +35,7 @@ __all__ = [
     "random_assignment",
     "rate_aware_assignment",
     "divisors",
+    "straggler_portfolio",
 ]
 
 
@@ -147,6 +148,30 @@ class PolicyCandidate:
             return 1.0 + self.hedge_fraction
         extra = max(2.0 * mean_min2 / mean - 1.0, 0.0)
         return 1.0 + self.hedge_fraction * extra
+
+
+def straggler_portfolio(
+    live: PolicyCandidate | None,
+    candidates: Sequence[PolicyCandidate] | None = None,
+) -> tuple[PolicyCandidate, ...] | None:
+    """The policy axis a re-plan scores, from the live policy and the
+    configured portfolio: the ``candidates`` when given, else ``(live,)``
+    when a mitigation runs, else None (plain replication only).
+
+    ``Objective`` prepends the ``'none'`` baseline, so a live clone at
+    ``q`` is scored as ``(none, clone q)`` under the same stability gate
+    as any portfolio.
+
+    >>> straggler_portfolio(PolicyCandidate("clone", quantile=0.9))
+    (PolicyCandidate(kind='clone', quantile=0.9, hedge_fraction=1.0),)
+    >>> straggler_portfolio(None) is None
+    True
+    """
+    if candidates:
+        return tuple(candidates)
+    if live is not None:
+        return (live,)
+    return None
 
 
 @dataclasses.dataclass(frozen=True)

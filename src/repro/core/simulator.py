@@ -32,7 +32,7 @@ of data at rate multiplier ``c`` draws ``s*Delta + E / (mu*c/s)`` with
 The engine is distribution-agnostic: besides the paper's Exp/SExp families
 it accepts :class:`~repro.core.order_stats.Empirical` (ECDF) distributions
 on EVERY sampling path — batch-completion sweeps (numpy and jax backends),
-sojourn/queueing sweeps, speculative sweeps, and the runtime
+sojourn/queueing and straggler-policy sweeps, and the runtime
 :class:`StepTimeSimulator`.  Empirical sampling stays on the shared CRN
 draw matrix via quantile coupling (see :func:`_empirical_coupled_times`):
 uniform positions derived from the shared exponential draws are pushed
@@ -70,7 +70,6 @@ from .policies import (
 __all__ = [
     "SimResult",
     "SweepSimResult",
-    "SpeculativeSweepResult",
     "PolicySweepResult",
     "CodedSweepResult",
     "ServingSweepResult",
@@ -79,13 +78,11 @@ __all__ = [
     "simulate_coverage",
     "simulate_coverage_reference",
     "simulate_sojourn",
-    "simulate_sojourn_quantiles",
     "simulate_sojourn_policies",
     "simulate_sojourn_serving",
     "sweep_simulate",
     "sweep_coded",
     "sweep_sojourn",
-    "sweep_sojourn_speculative",
     "sweep_sojourn_policies",
     "sweep_sojourn_coded",
     "sweep_sojourn_serving",
@@ -1230,7 +1227,6 @@ def simulate_sojourn(
     job_load: float = 1.0,
     warmup: int | None = None,
     worker_batch: Sequence[int] | None = None,
-    speculation_quantile: float | None = None,
     arrivals: Sequence[float] | None = None,
 ) -> SimResult:
     """Sojourn times of one (B, r) split under Poisson batch-job arrivals.
@@ -1245,25 +1241,17 @@ def simulate_sojourn(
     then grow with the horizon, which is exactly the signal that makes an
     unstable B lose the planner's argmin.
 
-    ``speculation_quantile`` switches on the clone-attack model
-    (:func:`_sojourn_recursion_speculative`): a job late relative to that
-    empirical quantile of its set-service distribution grabs an idle set
-    for one speculative clone.  ``None`` (default) is bit-identical to the
-    pre-speculation path — the clone draws are only consumed when enabled.
-
     ``arrivals`` overrides the Poisson arrival sequence with explicit
     absolute offsets (e.g. the serving engine's MMPP/trace offsets, cycled
     to ``n_jobs`` — see :func:`_resolve_arrivals`), so the planner scores
     the process the engine actually runs instead of silently assuming
-    Poisson.
+    Poisson.  A straggler policy's sojourns come from
+    :func:`simulate_sojourn_policies`, whose ``'none'`` entry is this call.
     """
-    wb, rates_arr, warm = _resolve_sojourn_args(
-        n_workers, n_batches, arrival_rate, (speculation_quantile,),
-        n_jobs, rates, job_load, warmup, worker_batch,
-    )
-    samples = _sojourn_quantile_samples(
-        dist, n_workers, n_batches, arrival_rate, (speculation_quantile,),
-        n_jobs, seed, rates_arr, job_load, warm, wb, arrivals=arrivals,
+    samples = simulate_sojourn_policies(
+        dist, n_workers, n_batches, arrival_rate, (PolicyCandidate(),),
+        n_jobs=n_jobs, seed=seed, rates=rates, job_load=job_load,
+        warmup=warmup, worker_batch=worker_batch, arrivals=arrivals,
     )
     return SimResult(samples[0])
 
@@ -1276,17 +1264,12 @@ def _validate_load(arrival_rate: float, job_load: float) -> None:
 
 
 def _resolve_sojourn_args(
-    n_workers, n_batches, arrival_rate, quantiles,
+    n_workers, n_batches, arrival_rate,
     n_jobs, rates, job_load, warmup, worker_batch,
 ):
-    """Shared validation + worker->set map resolution for the per-B sojourn
-    entry points (one place, so the argument contract cannot drift)."""
+    """Validation + worker->set map resolution for the per-B sojourn
+    entry point."""
     _validate_load(arrival_rate, job_load)
-    for q in quantiles:
-        if q is not None and not 0.0 < q < 1.0:
-            raise ValueError(
-                f"speculation quantile must be in (0, 1), got {q}"
-            )
     if worker_batch is None:
         if n_workers % n_batches:
             raise ValueError(f"B={n_batches} must divide N={n_workers}")
@@ -1296,70 +1279,6 @@ def _resolve_sojourn_args(
         if wb.shape != (n_workers,):
             raise ValueError(f"worker_batch shape {wb.shape} != ({n_workers},)")
     return wb, _validate_rates(rates, n_workers), _resolve_warmup(n_jobs, warmup)
-
-
-def _sojourn_quantile_samples(
-    dist, n_workers, n_batches, arrival_rate, quantiles,
-    n_jobs, seed, rates_arr, job_load, warm, wb, arrivals=None,
-) -> list[np.ndarray]:
-    """Post-warmup sojourns for ONE (B, placement) at several speculation
-    triggers, from one draw set (arrivals + primary matrix + — lazily, only
-    when some trigger is not None — one clone matrix).  The lazy clone draw
-    keeps the ``(None,)`` call bit-identical to the pre-speculation path."""
-    rng = np.random.default_rng(seed)
-    arrivals = _resolve_arrivals(arrivals, n_jobs, arrival_rate, rng)
-    unit = rng.standard_exponential((n_jobs, n_workers))
-    core = _unit_times(unit, dist, rates_arr) * job_load
-    svc = _group_min_times(core, wb, n_batches)
-    clone_svc = None
-    out = []
-    for q in quantiles:
-        if q is None:
-            out.append(_sojourn_recursion(arrivals, svc, n_batches)[warm:])
-            continue
-        if clone_svc is None:
-            clone_unit = rng.standard_exponential((n_jobs, n_workers))
-            clone_core = _unit_times(clone_unit, dist, rates_arr) * job_load
-            clone_svc = _group_min_times(clone_core, wb, n_batches)
-        threshold = float(np.quantile(svc, q))
-        sojourn, _ = _sojourn_recursion_speculative(
-            arrivals, svc, clone_svc, n_batches, threshold
-        )
-        out.append(sojourn[warm:])
-    return out
-
-
-def simulate_sojourn_quantiles(
-    dist: ServiceDistribution,
-    n_workers: int,
-    n_batches: int,
-    arrival_rate: float,
-    quantiles: Sequence[float | None],
-    n_jobs: int = 4_000,
-    seed: int = 0,
-    rates: Sequence[float] | None = None,
-    job_load: float = 1.0,
-    warmup: int | None = None,
-    worker_batch: Sequence[int] | None = None,
-    arrivals: Sequence[float] | None = None,
-) -> list[np.ndarray]:
-    """Sojourn samples of ONE (B, placement) at several clone triggers.
-
-    The per-B companion of :func:`sweep_sojourn_speculative` for callers
-    that supply an explicit ``worker_batch`` (the rate-aware planner): all
-    triggers share one arrival sequence + draw matrix + clone matrix, and
-    entry ``k`` is bit-identical to ``simulate_sojourn(...,
-    speculation_quantile=quantiles[k])`` at the same seed.  ``arrivals``
-    overrides the Poisson arrival sequence (see :func:`simulate_sojourn`).
-    """
-    wb, rates_arr, warm = _resolve_sojourn_args(
-        n_workers, n_batches, arrival_rate, quantiles,
-        n_jobs, rates, job_load, warmup, worker_batch,
-    )
-    return _sojourn_quantile_samples(
-        dist, n_workers, n_batches, arrival_rate, tuple(quantiles),
-        n_jobs, seed, rates_arr, job_load, warm, wb, arrivals=arrivals,
-    )
 
 
 def sweep_sojourn(
@@ -1444,156 +1363,6 @@ def sweep_sojourn(
     )
 
 
-@dataclasses.dataclass(frozen=True)
-class SpeculativeSweepResult:
-    """Sojourn samples for every (distribution, B, late-quantile) cell.
-
-    The speculative twin of :class:`SweepSimResult`: ``samples[d, s, q]``
-    holds the post-warmup sojourns of ``dists[d]`` at ``splits[s]`` batches
-    under the speculation trigger ``quantiles[q]`` (``None`` = no
-    speculation), all from ONE shared arrival sequence + draw matrix + clone
-    draw matrix, so (B, quantile) comparisons are variance-reduced.
-    ``clone_fraction[d, s, q]`` is the fraction of jobs that launched a
-    speculative clone — the capacity price of each trigger setting.
-    ``backend`` records the engine that actually produced the samples
-    (provenance for the planner's Plan and the bench harness).
-    """
-
-    n_workers: int
-    splits: tuple[int, ...]
-    quantiles: tuple[float | None, ...]
-    dists: tuple[ServiceDistribution, ...]
-    samples: np.ndarray  # (n_dists, n_splits, n_quantiles, n_jobs - warmup)
-    clone_fraction: np.ndarray  # (n_dists, n_splits, n_quantiles)
-    backend: str = "numpy"
-
-    def result(
-        self,
-        n_batches: int,
-        quantile: float | None,
-        dist_index: int = 0,
-    ) -> SimResult:
-        return SimResult(
-            self.samples[
-                dist_index,
-                self.splits.index(n_batches),
-                self.quantiles.index(quantile),
-            ]
-        )
-
-
-def sweep_sojourn_speculative(
-    dists: ServiceDistribution | Sequence[ServiceDistribution],
-    n_workers: int,
-    arrival_rate: float,
-    quantiles: Sequence[float | None],
-    n_jobs: int = 4_000,
-    seed: int = 0,
-    feasible_b: Sequence[int] | None = None,
-    rates: Sequence[float] | None = None,
-    job_load: float = 1.0,
-    warmup: int | None = None,
-    arrivals: Sequence[float] | None = None,
-    backend: str = "numpy",
-    mesh=None,
-) -> SpeculativeSweepResult:
-    """Sojourns for ALL (B, speculation-quantile) pairs x distributions.
-
-    The planner's scoring engine for speculative re-dispatch: every cell
-    shares ONE arrival sequence, ONE primary draw matrix, and ONE clone draw
-    matrix (common random numbers), so the argmin over (B, quantile) — and
-    the comparison against the ``None`` no-speculation cells — measures pure
-    policy effect, not sampling noise.  Each ``quantile=None`` cell is
-    bit-identical to the matching :func:`sweep_sojourn` cell at the same
-    seed; each ``quantile=q`` cell matches ``simulate_sojourn(...,
-    speculation_quantile=q)``.  ``arrivals`` overrides the Poisson arrival
-    sequence (see :func:`sweep_sojourn`).  ``backend``/``mesh`` select the
-    cell engine exactly as in :func:`sweep_sojourn` — on the accelerated
-    backends each quantile maps to its equivalent
-    ``PolicyCandidate('clone', q)`` cell.
-    """
-    dist_seq = _normalize_dists(dists)
-    splits = list(feasible_b) if feasible_b is not None else divisors(n_workers)
-    if not splits:
-        raise ValueError("no feasible B values")
-    for b in splits:
-        if n_workers % b:
-            raise ValueError(f"B={b} infeasible: must divide N={n_workers}")
-    q_seq = tuple(quantiles)
-    if not q_seq:
-        raise ValueError("at least one speculation quantile required")
-    for q in q_seq:
-        if q is not None and not 0.0 < q < 1.0:
-            raise ValueError(f"speculation quantile must be in (0, 1), got {q}")
-    _validate_load(arrival_rate, job_load)
-    rates_arr = _validate_rates(rates, n_workers)
-    warm = _resolve_warmup(n_jobs, warmup)
-    backend = resolve_sweep_backend(backend)
-    arrivals_given = arrivals is not None
-
-    rng = np.random.default_rng(seed)
-    arrivals = _resolve_arrivals(arrivals, n_jobs, arrival_rate, rng)
-    unit = rng.standard_exponential((n_jobs, n_workers))
-    clone_unit = rng.standard_exponential((n_jobs, n_workers))
-
-    if backend != "numpy":
-        pol_seq = tuple(
-            PolicyCandidate("none") if q is None else PolicyCandidate("clone", q)
-            for q in q_seq
-        )
-        cache_key = ("sojourn", seed, n_jobs, n_workers, arrivals_given,
-                     tuple(splits), None)
-        samples, clones = _sweep_policies_accel(
-            dist_seq, splits, pol_seq, arrivals, unit, clone_unit, rates_arr,
-            job_load, n_workers, warm, backend, mesh, None, cache_key,
-        )
-        return SpeculativeSweepResult(
-            n_workers=n_workers,
-            splits=tuple(splits),
-            quantiles=q_seq,
-            dists=dist_seq,
-            samples=samples,
-            clone_fraction=clones,
-            backend=backend,
-        )
-
-    order = _shared_draw_order(dist_seq, unit)
-    clone_order = _shared_draw_order(dist_seq, clone_unit)
-    samples = np.empty((len(dist_seq), len(splits), len(q_seq), n_jobs - warm))
-    clones = np.zeros((len(dist_seq), len(splits), len(q_seq)))
-    for di, dist in enumerate(dist_seq):
-        core = _unit_times(unit, dist, rates_arr, order=order) * job_load
-        clone_core = (
-            _unit_times(clone_unit, dist, rates_arr, order=clone_order)
-            * job_load
-        )
-        for si, b in enumerate(splits):
-            r = n_workers // b
-            svc = core.reshape(n_jobs, b, r).min(axis=2)
-            clone_svc = clone_core.reshape(n_jobs, b, r).min(axis=2)
-            for qi, q in enumerate(q_seq):
-                if q is None:
-                    samples[di, si, qi] = _sojourn_recursion(
-                        arrivals, svc, b
-                    )[warm:]
-                else:
-                    threshold = float(np.quantile(svc, q))
-                    soj, n_clones = _sojourn_recursion_speculative(
-                        arrivals, svc, clone_svc, b, threshold
-                    )
-                    samples[di, si, qi] = soj[warm:]
-                    clones[di, si, qi] = n_clones / n_jobs
-    return SpeculativeSweepResult(
-        n_workers=n_workers,
-        splits=tuple(splits),
-        quantiles=q_seq,
-        dists=dist_seq,
-        samples=samples,
-        clone_fraction=clones,
-        backend=backend,
-    )
-
-
 def simulate_sojourn_policies(
     dist: ServiceDistribution,
     n_workers: int,
@@ -1612,22 +1381,21 @@ def simulate_sojourn_policies(
     """Sojourn samples of ONE (B, placement) under several straggler
     policies.
 
-    The policy-portfolio companion of :func:`simulate_sojourn_quantiles`
-    (and the per-B path the rate-aware planner uses): every candidate
-    shares one arrival sequence + primary draw matrix + — lazily, only
-    when some candidate is not ``'none'`` — one alternate draw matrix (the
-    clone/relaunch/hedge draws).  A ``PolicyCandidate('clone', q)`` entry
-    is bit-identical to ``simulate_sojourn_quantiles`` at quantile ``q``
-    and the same seed; disabled relaunch/hedged candidates are
-    bit-identical to the plain path (the CRN parity contracts the tests
-    pin).  ``backend`` selects the cell engine as in
+    The per-B companion of :func:`sweep_sojourn_policies` (and the path
+    the rate-aware planner uses): every candidate shares one arrival
+    sequence + primary draw matrix + — lazily, only when some candidate is
+    not ``'none'`` — one alternate draw matrix (the clone/relaunch/hedge
+    draws).  A ``'none'`` entry is bit-identical to
+    :func:`simulate_sojourn` at the same seed, and so are disabled
+    relaunch/hedged candidates (the CRN parity contracts the tests pin).
+    ``backend`` selects the cell engine as in
     :func:`sweep_sojourn_policies`; the lazy alternate draw is preserved
     on every backend, so RNG consumption (and hence any later draw from
     the same seed) is backend-independent.
     """
     pol_seq = _validate_policies(policies)
     wb, rates_arr, warm = _resolve_sojourn_args(
-        n_workers, n_batches, arrival_rate, (None,),
+        n_workers, n_batches, arrival_rate,
         n_jobs, rates, job_load, warmup, worker_batch,
     )
     backend = resolve_sweep_backend(backend)
@@ -1666,7 +1434,7 @@ def simulate_sojourn_policies(
 class PolicySweepResult:
     """Sojourn samples for every (distribution, B, policy) cell.
 
-    The policy-portfolio twin of :class:`SpeculativeSweepResult`:
+    The policy-portfolio twin of :class:`SweepSimResult`:
     ``samples[d, s, p]`` holds the post-warmup sojourns of ``dists[d]`` at
     ``splits[s]`` batches under ``policies[p]``, all from ONE shared
     arrival sequence + primary draw matrix + alternate draw matrix, so
@@ -1724,9 +1492,10 @@ def sweep_sojourn_policies(
     (B, policy) — clone vs relaunch vs hedged vs none — measures pure
     policy effect, not sampling noise.  Each ``PolicyCandidate('none')``
     cell is bit-identical to the matching :func:`sweep_sojourn` cell at
-    the same seed; each ``('clone', q)`` cell matches the
-    :func:`sweep_sojourn_speculative` cell at quantile ``q``; disabled
-    relaunch/hedged candidates match the ``'none'`` cells bit-for-bit.
+    the same seed; each ``('clone', q)`` cell is the clone-attack
+    recursion (:func:`_sojourn_recursion_speculative`) at that set-service
+    quantile; disabled relaunch/hedged candidates match the ``'none'``
+    cells bit-for-bit.
     ``arrivals`` overrides the Poisson arrival sequence (see
     :func:`sweep_sojourn`).
 

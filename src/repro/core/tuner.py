@@ -187,7 +187,6 @@ class StragglerTuner:
         planner: Planner | None = None,
         batch_divisor: int | None = None,
         job_load: float = 1.0,
-        speculation_quantiles: tuple[float, ...] | None = None,
         policy_candidates: tuple | None = None,
         arrival_offsets: np.ndarray | None = None,
         coding_candidates: tuple | None = None,
@@ -206,28 +205,16 @@ class StragglerTuner:
         # units of data one batch-job carries (serving: batch tokens / unit);
         # scales the load-aware objective's service model
         self.job_load = job_load
-        # clone triggers the serving master is running: load-aware re-plans
-        # must score candidate B WITH speculation, else a fleet that is only
-        # stable because it speculates looks saturated to the planner
-        self.speculation_quantiles = (
-            tuple(float(q) for q in speculation_quantiles)
-            if speculation_quantiles
-            else None
-        )
         # straggler-policy portfolio: when set, load-aware re-plans score
         # every (B, candidate) cell and land the winner on Plan.policy —
         # this is how the tuner switches policy online when the fitted /
-        # empirical distribution drifts across a regime boundary.
-        # Mutually exclusive with speculation_quantiles (Objective enforces).
+        # empirical distribution drifts across a regime boundary.  A
+        # serving master that runs a mitigation passes it here (at least
+        # as a one-candidate portfolio), else a fleet that is only stable
+        # because it mitigates looks saturated to the planner.
         self.policy_candidates = (
             tuple(policy_candidates) if policy_candidates else None
         )
-        if self.policy_candidates and self.speculation_quantiles:
-            raise ValueError(
-                "policy_candidates and speculation_quantiles are mutually "
-                "exclusive: the portfolio subsumes the clone-trigger sweep "
-                "(use PolicyCandidate('clone', quantile=q) candidates)"
-            )
         # coded-computation portfolio: when set, every re-plan races the
         # listed CodingCandidates (cyclic / MDS / poly, measured overheads)
         # against the replication sweep on shared CRN draws and lands a
@@ -260,12 +247,6 @@ class StragglerTuner:
                 raise ValueError(
                     "slo_classes requires serving_batch_size (the request "
                     "rate is the observed job rate times the batch size)"
-                )
-            if self.speculation_quantiles:
-                raise ValueError(
-                    "slo_classes and speculation_quantiles are mutually "
-                    "exclusive; use PolicyCandidate('clone', quantile=q) "
-                    "entries in policy_candidates"
                 )
             if self.coding_candidates:
                 raise ValueError(
@@ -645,7 +626,6 @@ class StragglerTuner:
                 arrival_rate=rate,
                 utilization=None,
                 job_load=self.job_load,
-                speculation_quantiles=self.speculation_quantiles,
                 policies=self.policy_candidates,
                 arrivals=self.arrival_offsets,
             )

@@ -154,7 +154,6 @@ def run_serving(sc: ServeConfig):
         "sojourn_by_B": sojourn,
         "sojourn_best_B": plan.n_batches,
         "policy": plan.policy,
-        "speculation_quantile": plan.speculation_quantile,
         "speculative_p99": plan.score,
     }
 

@@ -24,7 +24,6 @@ from repro.serving.queueing import (
     QueuePolicy,
     RelaunchPolicy,
     Request,
-    SpeculationPolicy,
     StragglerPolicy,
     partition_requests,
 )
@@ -47,7 +46,6 @@ __all__ = [
     "Request",
     "RequestStats",
     "ServeEngineConfig",
-    "SpeculationPolicy",
     "StragglerPolicy",
     "TraceArrivals",
     "make_arrivals",

@@ -55,6 +55,7 @@ from repro.core import (
     StragglerTuner,
     TunerConfig,
     make_planner,
+    straggler_portfolio,
 )
 from repro.serving.arrivals import ArrivalProcess, make_arrivals
 from repro.serving.queueing import (
@@ -160,8 +161,9 @@ class ServeEngineConfig:
     # launch a clone of a batch onto an idle replica-set when its first
     # response is later than this quantile of the fitted min-over-replicas
     # service distribution (None = no speculation); clone_budget caps the
-    # clones per batch job.  The same quantile seeds the planner objective,
-    # so plan_initial / tuner re-plans score candidate B with speculation on.
+    # clones per batch job.  The live policy seeds the planner objective's
+    # portfolio (core.straggler_portfolio), so plan_initial / tuner re-plans
+    # score candidate B with the mitigation on.
     speculation_quantile: Optional[float] = None
     clone_budget: int = 1
     # which mitigation the live trigger drives: 'clone' copies a late batch
@@ -173,8 +175,8 @@ class ServeEngineConfig:
     hedge_fraction: float = 1.0  # fraction of jobs hedged ('hedged' only)
     # adaptive portfolio: PolicyCandidate tuple the tuner's load-aware
     # re-plans score per candidate B; the winner lands on Plan.policy and
-    # the engine adopts it live (the online policy-switch loop).  Overrides
-    # the speculation_quantile-seeded trigger sweep in re-plan objectives.
+    # the engine adopts it live (the online policy-switch loop).  Replaces
+    # the live policy as the re-plan objectives' portfolio.
     policy_candidates: Optional[tuple[PolicyCandidate, ...]] = None
     # coded-computation portfolio: CodingCandidate tuple every planner
     # objective (initial plan + tuner re-plans) races against the
@@ -342,9 +344,7 @@ class ReplicatedServingEngine:
             # load-aware re-plans score candidate B with the SAME straggler
             # mitigation the master runs (else a fleet stable only because
             # it mitigates looks saturated and re-plans to no-replication):
-            # an explicit portfolio when configured, a single-candidate
-            # portfolio for relaunch/hedged, the legacy clone-trigger sweep
-            # otherwise
+            # the configured portfolio, else the live policy alone
             **self._tuner_decision_kwargs(),
             arrival_offsets=self._job_arrival_offsets,
         )
@@ -434,25 +434,6 @@ class ReplicatedServingEngine:
             sc.straggler_policy, quantile=sc.speculation_quantile
         )
 
-    @property
-    def speculation_quantile(self) -> Optional[float]:
-        """The live CLONE trigger (legacy mirror — None whenever the live
-        policy is anything other than a trigger-driven clone, same rule as
-        ``Plan.speculation_quantile``)."""
-        pol = self.policy
-        if pol is not None and pol.kind == "clone":
-            return pol.quantile
-        return None
-
-    @speculation_quantile.setter
-    def speculation_quantile(self, q: Optional[float]) -> None:
-        # legacy shim: assigning a trigger installs/uninstalls a clone policy
-        self.policy = (
-            PolicyCandidate("clone", quantile=float(q))
-            if q is not None
-            else None
-        )
-
     def _trigger_quantile(self) -> Optional[float]:
         """The live policy's late trigger (clone OR relaunch; None = off)."""
         pol = self.policy
@@ -484,12 +465,10 @@ class ReplicatedServingEngine:
             if sc.coding_candidates
             else {}
         )
+        policies = straggler_portfolio(self.policy, sc.policy_candidates)
         if sc.slo_classes:
-            # serving sweep: the (max_wait, shed) axes ride along, and the
-            # mitigation axis must be a portfolio (the serving sweep has no
-            # legacy clone-trigger path) — the live policy becomes a
-            # single-candidate portfolio when none is configured
-            serving = {
+            # serving sweep: the (max_wait, shed) axes ride along
+            return {
                 "slo_classes": tuple(sc.slo_classes),
                 "serving_batch_size": sc.batch_size,
                 "max_wait_candidates": (
@@ -500,25 +479,9 @@ class ReplicatedServingEngine:
                 "shed_candidates": (
                     tuple(sc.shed_candidates) if sc.shed_candidates else None
                 ),
+                "policy_candidates": policies,
             }
-            if sc.policy_candidates:
-                serving["policy_candidates"] = tuple(sc.policy_candidates)
-            elif self.policy is not None:
-                serving["policy_candidates"] = (self.policy,)
-            return serving
-        if sc.policy_candidates:
-            return {"policy_candidates": tuple(sc.policy_candidates), **coding}
-        pol = self.policy
-        if pol is not None and pol.kind in ("relaunch", "hedged"):
-            return {"policy_candidates": (pol,), **coding}
-        return {
-            "speculation_quantiles": (
-                (pol.quantile,)
-                if pol is not None and pol.kind == "clone"
-                else None
-            ),
-            **coding,
-        }
+        return {"policy_candidates": policies, **coding}
 
     # -- objective / arrivals ------------------------------------------------
     def _work(self, n_reqs: int) -> float:
@@ -585,25 +548,13 @@ class ReplicatedServingEngine:
                 "both (same rule as Objective)"
             )
         load_aware = sc.arrival_rate is not None or sc.utilization is not None
-        pol = self.policy
-        policies: Optional[tuple[PolicyCandidate, ...]] = None
-        spec_qs: Optional[tuple[float, ...]] = None
-        if load_aware:
-            # the planner scores candidate B under the SAME mitigation the
-            # master runs: an explicit portfolio when configured, a single-
-            # candidate portfolio for relaunch/hedged, the legacy clone-
-            # trigger sweep otherwise
-            if sc.policy_candidates:
-                policies = tuple(sc.policy_candidates)
-            elif pol is not None and pol.kind in ("relaunch", "hedged"):
-                policies = (pol,)
-            elif pol is not None and pol.kind == "clone":
-                # the serving sweep has no legacy clone-trigger path: a live
-                # clone policy rides as a single-candidate portfolio there
-                if sc.slo_classes:
-                    policies = (pol,)
-                else:
-                    spec_qs = (pol.quantile,)
+        # the planner scores candidate B under the SAME mitigation the
+        # master runs (straggler policies are scored on sojourn only)
+        policies = (
+            straggler_portfolio(self.policy, sc.policy_candidates)
+            if load_aware
+            else None
+        )
         if sc.coding_candidates and sc.planner_mode == "analytic":
             raise ValueError(
                 "coding_candidates needs a simulation-capable planner_mode "
@@ -619,7 +570,6 @@ class ReplicatedServingEngine:
             ),
             utilization=sc.utilization,
             job_load=self._work(sc.batch_size),
-            speculation_quantiles=spec_qs,
             policies=policies,
             coding=(
                 tuple(sc.coding_candidates) if sc.coding_candidates else None
@@ -841,8 +791,8 @@ class ReplicatedServingEngine:
                 if rp is not None:
                     self.plan = self.tuner.apply(rp)
                     # adopt the mitigation the winning score assumed: when the
-                    # re-plan swept (B, policy) or (B, trigger) cells, run what
-                    # it scored — including "don't mitigate at this B" (None)
+                    # re-plan swept (B, policy) cells, run what it scored —
+                    # including "don't mitigate at this B" (None)
                     if rp.plan is not None and rp.plan.objective.coding:
                         self.last_coding = rp.plan.coding
                     if rp.plan is not None and rp.plan.objective.slo_classes:
@@ -856,13 +806,6 @@ class ReplicatedServingEngine:
                         }
                     if rp.plan is not None and rp.plan.objective.policies:
                         self._adopt_policy(rp.plan)
-                    elif (
-                        rp.plan is not None
-                        and rp.plan.objective.speculation_quantiles
-                    ):
-                        self.speculation_quantile = (
-                            rp.plan.speculation_quantile
-                        )
                     return {"n_groups": self.plan.n_batches}
                 # no B move, but the last evaluated sweep may still have found
                 # a better policy/trigger AT the current B — adopting it needs
@@ -879,8 +822,6 @@ class ReplicatedServingEngine:
                             self.last_master.swap_policy(self._queue_policy())
                     elif lp.objective.policies:
                         self._adopt_policy(lp)
-                    elif lp.objective.speculation_quantiles:
-                        self.speculation_quantile = lp.speculation_quantile
             return None
 
     def serve(
@@ -972,7 +913,7 @@ class ReplicatedServingEngine:
                 policy=self._queue_policy(),
                 clock=self.clock,
                 on_job_complete=self._on_job_complete,
-                speculation=self._speculation_policy(),
+                straggler_policy=self._speculation_policy(),
                 # a dropped request resolved as a miss without reaching any job
                 # callback: stream it into the tuner AS IT HAPPENS, per request
                 # and class-attributed (see _on_drop)

@@ -41,10 +41,9 @@ groups.  The master's event loop:
   about late responses, all variants sharing the same event clock,
   first-completion-wins cancellation, and censored-telemetry accounting:
 
-  - :class:`ClonePolicy` (speculative re-dispatch, the PR-4 behavior and
-    the alias :class:`SpeculationPolicy`): a batch whose first response is
-    LATE (no response by the policy's late-quantile threshold after
-    dispatch) is cloned onto an idle replica-set, Aktaş et al.
+  - :class:`ClonePolicy` (speculative re-dispatch): a batch whose first
+    response is LATE (no response by the policy's late-quantile threshold
+    after dispatch) is cloned onto an idle replica-set, Aktaş et al.
     clone-attack style — the clone's ``r`` replicas race the originals,
     whichever responds first completes the batch, and every other replica
     is cancelled.  Clones only ever take sets that are idle at the trigger
@@ -91,7 +90,6 @@ __all__ = [
     "StragglerPolicy",
     "NoOpPolicy",
     "ClonePolicy",
-    "SpeculationPolicy",
     "RelaunchPolicy",
     "HedgedDispatchPolicy",
     "Request",
@@ -217,11 +215,11 @@ class StragglerPolicy:
     """Base class of the master's straggler-mitigation policies.
 
     One policy instance is wired into :class:`EventDrivenMaster` (the
-    ``speculation=`` / ``straggler_policy=`` knob); concrete subclasses are
-    :class:`ClonePolicy` (and its legacy alias :class:`SpeculationPolicy`),
-    :class:`RelaunchPolicy`, :class:`HedgedDispatchPolicy`, and
-    :class:`NoOpPolicy`.  All share the master's event clock,
-    first-completion-wins cancellation, and censored-telemetry accounting.
+    ``straggler_policy=`` knob); concrete subclasses are
+    :class:`ClonePolicy`, :class:`RelaunchPolicy`,
+    :class:`HedgedDispatchPolicy`, and :class:`NoOpPolicy`.  All share the
+    master's event clock, first-completion-wins cancellation, and
+    censored-telemetry accounting.
     """
 
 
@@ -280,12 +278,6 @@ class ClonePolicy(StragglerPolicy):
             raise ValueError(
                 f"max_clones must be >= 0, got {self.max_clones}"
             )
-
-
-@dataclasses.dataclass(frozen=True)
-class SpeculationPolicy(ClonePolicy):
-    """Pre-portfolio name of :class:`ClonePolicy`, kept as an alias so
-    existing configs and pickles keep working (see docs/migration.md)."""
 
 
 @dataclasses.dataclass(frozen=True)
@@ -699,22 +691,14 @@ class EventDrivenMaster:
         policy: Optional[QueuePolicy] = None,
         clock: float = 0.0,
         on_job_complete: Optional[JobCallback] = None,
-        speculation: Optional[StragglerPolicy] = None,
         on_drop: Optional[Callable[[Request], None]] = None,
         straggler_policy: Optional[StragglerPolicy] = None,
     ):
         if n_groups < 1:
             raise ValueError(f"n_groups must be >= 1, got {n_groups}")
-        if speculation is not None and straggler_policy is not None:
-            raise ValueError(
-                "pass either speculation= or its alias straggler_policy=, "
-                "not both"
-            )
         self.n_groups = n_groups
         self.policy = policy or QueuePolicy()
-        self.speculation = (
-            speculation if speculation is not None else straggler_policy
-        )
+        self.straggler_policy = straggler_policy
         self._sampler = service_sampler
         self.clock = float(clock)
         self.on_job_complete = on_job_complete
@@ -927,7 +911,7 @@ class EventDrivenMaster:
 
     def _spec_threshold(self, job: BatchJob) -> Optional[float]:
         """Lateness threshold for one job (see :func:`late_threshold`)."""
-        return late_threshold(self.speculation, job, self._service_window)
+        return late_threshold(self.straggler_policy, job, self._service_window)
 
     def _arm_speculation(self, job: BatchJob) -> None:
         """Schedule the late-response check for a just-(re)dispatched job.
@@ -935,7 +919,7 @@ class EventDrivenMaster:
         Only the trigger-driven policies (clone, relaunch) arm; hedging
         acts at dispatch time and NoOp never acts.
         """
-        pol = self.speculation
+        pol = self.straggler_policy
         if isinstance(pol, ClonePolicy):
             if pol.max_clones <= job.n_clones:
                 return
@@ -952,7 +936,7 @@ class EventDrivenMaster:
         """Deterministic stride over dispatches: job n is hedged iff
         floor((n+1)f) > floor(nf), hitting exactly a ``hedge_fraction`` of
         jobs with no RNG (reproducible, CRN-friendly)."""
-        f = self.speculation.hedge_fraction
+        f = self.straggler_policy.hedge_fraction
         n = self._hedge_count
         self._hedge_count += 1
         return math.floor((n + 1) * f) > math.floor(n * f)
@@ -977,12 +961,12 @@ class EventDrivenMaster:
             job.completed = self.clock + float(job.service_times[job.winner])
             self._in_flight[group] = job
             if (
-                isinstance(self.speculation, HedgedDispatchPolicy)
+                isinstance(self.straggler_policy, HedgedDispatchPolicy)
                 and self._hedge_selected()
             ):
                 # hedged dispatch: grab up to k-1 ADDITIONAL idle sets now,
                 # racing from t=0 (idle-only, queued work never displaced)
-                for _ in range(self.speculation.k - 1):
+                for _ in range(self.straggler_policy.k - 1):
                     if not self._idle:
                         break
                     g2 = heapq.heappop(self._idle)
@@ -1006,10 +990,10 @@ class EventDrivenMaster:
             return  # the original responded first: the trigger is a no-op
         if self._reconfig is not None:
             return  # draining: never grow/redraw the in-flight footprint
-        if isinstance(self.speculation, RelaunchPolicy):
+        if isinstance(self.straggler_policy, RelaunchPolicy):
             self._relaunch(job)
             return
-        if job.n_clones >= self.speculation.max_clones:
+        if job.n_clones >= self.straggler_policy.max_clones:
             return  # clone budget exhausted
         if self._idle:
             group = heapq.heappop(self._idle)
@@ -1033,7 +1017,7 @@ class EventDrivenMaster:
         """Cancel the job's in-flight attempt and re-dispatch it fresh on
         the SAME replica-set (no extra capacity; the cancelled attempt is
         kept, censored at the relaunch instant, for telemetry)."""
-        if job.n_relaunches >= self.speculation.max_relaunches:
+        if job.n_relaunches >= self.straggler_policy.max_relaunches:
             return  # relaunch budget exhausted
         job.discarded_service_times.append(job.service_times)
         job.relaunched_at.append(self.clock)

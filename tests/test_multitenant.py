@@ -15,6 +15,7 @@ import pytest
 from _prop import given, settings, st
 from repro.core import (
     ClusterSpec,
+    CodingCandidate,
     EmpiricalPlanner,
     Exponential,
     Objective,
@@ -419,7 +420,7 @@ def test_tuner_class_miss_windows_and_guards():
     with pytest.raises(ValueError, match="mutually"):
         _tuner(
             slo_classes=CLASSES, serving_batch_size=4,
-            speculation_quantiles=(0.9,),
+            coding_candidates=(CodingCandidate(scheme="mds", s=1),),
         )
 
 

@@ -14,18 +14,18 @@ from repro.core import (
     ClusterSpec,
     Exponential,
     Objective,
+    PolicyCandidate,
     ReplicationPlan,
-    RescalePlan,
     ShiftedExponential,
     SimulatedPlanner,
     StragglerTuner,
     TunerConfig,
     simulate_sojourn,
+    simulate_sojourn_policies,
     sweep_sojourn,
-    sweep_sojourn_speculative,
 )
-from repro.core.simulator import simulate_sojourn_quantiles
 from repro.serving import (
+    ClonePolicy,
     DeterministicArrivals,
     EventDrivenMaster,
     MMPPArrivals,
@@ -34,7 +34,6 @@ from repro.serving import (
     ReplicatedServingEngine,
     Request,
     ServeEngineConfig,
-    SpeculationPolicy,
     TraceArrivals,
     make_arrivals,
     partition_requests,
@@ -43,6 +42,7 @@ from repro.serving import (
 # the Fig. 2-style SExp fleet used by the acceptance demonstration
 N_FLEET = 16
 FLEET_DIST = ShiftedExponential(delta=0.02, mu=2.0)
+CLONE_90 = PolicyCandidate("clone", quantile=0.9)
 
 
 # -- arrival processes --------------------------------------------------------
@@ -539,7 +539,7 @@ def test_speculation_clone_wins_and_cancels_originals():
     master = EventDrivenMaster(
         2, lambda job, g: next(svc),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(max_clones=1, threshold=lambda job: 2.0),
+        straggler_policy=ClonePolicy(max_clones=1, threshold=lambda job: 2.0),
     )
     master.submit(Request(request_id=0, arrival=0.0))
     jobs = master.run()
@@ -557,7 +557,7 @@ def test_speculation_after_original_completes_is_noop():
     master = EventDrivenMaster(
         2, lambda job, g: np.array([1.0]),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(threshold=lambda job: 2.0),
+        straggler_policy=ClonePolicy(threshold=lambda job: 2.0),
     )
     master.submit(Request(request_id=0, arrival=0.0))
     jobs = master.run()
@@ -573,7 +573,7 @@ def test_speculation_losing_clone_is_cancelled():
     master = EventDrivenMaster(
         2, lambda job, g: next(svc),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(max_clones=1, threshold=lambda job: 1.0),
+        straggler_policy=ClonePolicy(max_clones=1, threshold=lambda job: 1.0),
     )
     master.submit(Request(request_id=0, arrival=0.0))
     jobs = master.run()
@@ -591,7 +591,7 @@ def test_speculation_clone_budget_exhausted():
     master = EventDrivenMaster(
         4, lambda job, g: np.array([100.0]),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(max_clones=2, threshold=lambda job: 1.0),
+        straggler_policy=ClonePolicy(max_clones=2, threshold=lambda job: 1.0),
     )
     master.submit(Request(request_id=0, arrival=0.0))
     jobs = master.run()
@@ -600,7 +600,7 @@ def test_speculation_clone_budget_exhausted():
     zero = EventDrivenMaster(
         2, lambda job, g: np.array([5.0]),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(max_clones=0, threshold=lambda job: 1.0),
+        straggler_policy=ClonePolicy(max_clones=0, threshold=lambda job: 1.0),
     )
     zero.submit(Request(request_id=0, arrival=0.0))
     zero.run()
@@ -613,7 +613,7 @@ def test_speculation_needs_an_idle_set():
     master = EventDrivenMaster(
         1, lambda job, g: np.array([5.0]),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(max_clones=3, threshold=lambda job: 1.0),
+        straggler_policy=ClonePolicy(max_clones=3, threshold=lambda job: 1.0),
     )
     master.submit(Request(request_id=0, arrival=0.0))
     jobs = master.run()
@@ -628,7 +628,7 @@ def test_speculation_empirical_threshold_calibrates():
     master = EventDrivenMaster(
         2, lambda job, g: np.array([next(services)]),
         policy=QueuePolicy(max_batch_size=1),
-        speculation=SpeculationPolicy(
+        straggler_policy=ClonePolicy(
             late_quantile=0.5, max_clones=1, min_observations=4
         ),
     )
@@ -648,58 +648,29 @@ def test_mm1_with_speculation_matches_plain_and_closed_form():
     plain = simulate_sojourn(
         Exponential(mu=2.0), 1, 1, arrival_rate=1.0, n_jobs=20_000, seed=0
     )
-    spec = simulate_sojourn(
-        Exponential(mu=2.0), 1, 1, arrival_rate=1.0, n_jobs=20_000, seed=0,
-        speculation_quantile=0.9,
+    (spec,) = simulate_sojourn_policies(
+        Exponential(mu=2.0), 1, 1, arrival_rate=1.0,
+        policies=(CLONE_90,), n_jobs=20_000, seed=0,
     )
-    np.testing.assert_array_equal(spec.samples, plain.samples)
-    assert spec.mean == pytest.approx(1.0, rel=0.08)  # 1/(mu - lambda)
-
-
-def test_speculative_sweep_cells_match_single_sim():
-    """CRN contract: every (B, q) cell of the batched speculative sweep is
-    bit-identical to the standalone simulate_sojourn call; q=None cells
-    match the plain sweep path."""
-    lam = 8.0
-    res = sweep_sojourn_speculative(
-        FLEET_DIST, N_FLEET, arrival_rate=lam, quantiles=(None, 0.9),
-        n_jobs=1_500, seed=5,
-    )
-    for i, b in enumerate(res.splits):
-        plain = simulate_sojourn(
-            FLEET_DIST, N_FLEET, b, arrival_rate=lam, n_jobs=1_500, seed=5
-        )
-        spec = simulate_sojourn(
-            FLEET_DIST, N_FLEET, b, arrival_rate=lam, n_jobs=1_500, seed=5,
-            speculation_quantile=0.9,
-        )
-        np.testing.assert_array_equal(res.samples[0, i, 0], plain.samples)
-        np.testing.assert_array_equal(res.samples[0, i, 1], spec.samples)
-
-
-def test_objective_speculation_validation():
-    with pytest.raises(ValueError, match="load-aware"):
-        Objective(speculation_quantiles=(0.9,))  # speculation needs load
-    with pytest.raises(ValueError):
-        Objective(utilization=0.5, speculation_quantiles=(1.5,))
-    with pytest.raises(ValueError):
-        Objective(utilization=0.5, speculation_quantiles=())
-    ok = Objective(utilization=0.5, speculation_quantiles=(0.9,))
-    assert ok.speculation_quantiles == (0.9,)
+    np.testing.assert_array_equal(spec, plain.samples)
+    assert spec.mean() == pytest.approx(1.0, rel=0.08)  # 1/(mu - lambda)
 
 
 def test_planner_scores_speculation_pairs_on_heavy_fleet():
     """On the heavy-shift fleet (static replication unaffordable at u=0.7)
-    the planner must choose to speculate, record the trigger on the Plan,
+    the planner must choose to clone, record the trigger on the Plan,
     and never score worse than plain replication (same CRN draws)."""
     heavy = ClusterSpec(n_workers=16, dist=ShiftedExponential(0.5, 2.0))
     planner = SimulatedPlanner(n_trials=3_000, seed=0)
     plain = planner.plan(heavy, Objective(metric="p99", utilization=0.7))
     sp = planner.plan(heavy, Objective(
-        metric="p99", utilization=0.7, speculation_quantiles=(0.8, 0.9),
+        metric="p99", utilization=0.7,
+        policies=tuple(
+            PolicyCandidate("clone", quantile=q) for q in (0.8, 0.9)
+        ),
     ))
-    assert plain.speculation_quantile is None
-    assert sp.speculation_quantile in (0.8, 0.9)
+    assert plain.policy is None
+    assert sp.policy.kind == "clone" and sp.policy.quantile in (0.8, 0.9)
     assert sp.score <= plain.score
 
 
@@ -828,98 +799,52 @@ def test_engine_speculation_smoke():
     assert all(s.completion >= s.dispatched >= s.arrival for s in out["stats"])
 
 
-def test_simulate_sojourn_quantiles_bit_parity():
-    """The per-B multi-trigger helper (hoisted draws) matches standalone
-    simulate_sojourn calls entry for entry."""
-    sets = simulate_sojourn_quantiles(
-        FLEET_DIST, N_FLEET, 4, arrival_rate=8.0, quantiles=(None, 0.9),
-        n_jobs=1_500, seed=5,
+def test_simulate_sojourn_policies_bit_parity():
+    """The per-B multi-candidate helper (hoisted draws) matches standalone
+    calls entry for entry: 'none' is simulate_sojourn, and a clone
+    trigger's lazy alternate draw is the same with or without 'none'."""
+    sets = simulate_sojourn_policies(
+        FLEET_DIST, N_FLEET, 4, arrival_rate=8.0,
+        policies=(PolicyCandidate(), CLONE_90), n_jobs=1_500, seed=5,
     )
     plain = simulate_sojourn(
         FLEET_DIST, N_FLEET, 4, arrival_rate=8.0, n_jobs=1_500, seed=5
     )
-    spec = simulate_sojourn(
-        FLEET_DIST, N_FLEET, 4, arrival_rate=8.0, n_jobs=1_500, seed=5,
-        speculation_quantile=0.9,
+    (spec,) = simulate_sojourn_policies(
+        FLEET_DIST, N_FLEET, 4, arrival_rate=8.0, policies=(CLONE_90,),
+        n_jobs=1_500, seed=5,
     )
     np.testing.assert_array_equal(sets[0], plain.samples)
-    np.testing.assert_array_equal(sets[1], spec.samples)
-
-
-def test_engine_adopts_replan_speculation_trigger(monkeypatch):
-    """When a load-aware re-plan swept (B, trigger) pairs, the engine must
-    run the trigger the winning score assumed — including disabling
-    speculation when the planner found plain replication better."""
-    eng = ReplicatedServingEngine(ServeEngineConfig(
-        n_server_groups=8, n_batches=8, batch_size=2, delta=0.02, mu=2.0,
-        utilization=0.7, execute_model=False, seed=0, tuner=True,
-        planner_mode="simulate", speculation_quantile=0.8,
-    ))
-    assert eng.speculation_quantile == 0.8
-    plan = eng.planner.plan(
-        ClusterSpec(n_workers=8, dist=eng.dist),
-        Objective(metric="mean", arrival_rate=4.0,
-                  speculation_quantiles=(0.8,)),
-    )
-    plan = dataclasses.replace(
-        plan, speculation_quantile=None,
-        replication=ReplicationPlan(n_data=8, n_batches=4),
-    )
-    rp = RescalePlan(old_batches=8, new_batches=4, predicted_old=1.0,
-                     predicted_new=0.5, fit=None, step=0, plan=plan)
-    monkeypatch.setattr(eng.tuner, "maybe_replan", lambda: rp)
-    eng.serve(20)  # first completed job applies the re-plan
-    assert eng.plan.n_batches == 4
-    assert eng.speculation_quantile is None  # trigger adopted (disabled)
-    assert eng._speculation_policy() is None
-
-
-def test_engine_adopts_trigger_change_at_same_b(monkeypatch):
-    """A sweep that keeps B but prefers a different trigger still updates
-    the engine — a trigger change needs no drain, so it rides along even
-    when no RescalePlan is emitted."""
-    eng = ReplicatedServingEngine(ServeEngineConfig(
-        n_server_groups=8, n_batches=8, batch_size=2, delta=0.02, mu=2.0,
-        utilization=0.7, execute_model=False, seed=0, tuner=True,
-        planner_mode="simulate", speculation_quantile=0.8,
-    ))
-    lp = eng.planner.plan(
-        ClusterSpec(n_workers=8, dist=eng.dist, feasible_b=(8,)),
-        Objective(metric="mean", arrival_rate=4.0,
-                  speculation_quantiles=(0.95,)),
-    )
-    lp = dataclasses.replace(lp, speculation_quantile=0.95)
-    monkeypatch.setattr(eng.tuner, "maybe_replan", lambda: None)
-    eng.tuner.last_plan = lp
-    eng.serve(10)
-    assert eng.plan.n_batches == 8  # no move
-    assert eng.speculation_quantile == 0.95  # trigger adopted anyway
+    np.testing.assert_array_equal(sets[1], spec)
 
 
 def test_tuner_objective_carries_speculation_triggers():
     """A load-aware re-plan must score candidate B with the SAME clone
     trigger the serving master runs — otherwise a fleet that is only
     stable because it speculates looks saturated to the planner."""
+    clone = PolicyCandidate("clone", quantile=0.8)
     tuner = StragglerTuner(
         ReplicationPlan(n_data=8, n_batches=4),
         TunerConfig(mode="simulate"),
-        speculation_quantiles=(0.8,),
+        policy_candidates=(clone,),
     )
     tuner.observe_load(3.0)
-    assert tuner.objective().speculation_quantiles == (0.8,)
-    # without load telemetry the objective stays load-free (speculation
+    assert tuner.objective().policies == (PolicyCandidate(), clone)
+    # without load telemetry the objective stays load-free (policy
     # scoring needs queueing), and the engine threads its config through
     fresh = StragglerTuner(
         ReplicationPlan(n_data=8, n_batches=4),
         TunerConfig(mode="simulate"),
-        speculation_quantiles=(0.8,),
+        policy_candidates=(clone,),
     )
-    assert fresh.objective().speculation_quantiles is None
+    assert fresh.objective().policies is None
     eng = ReplicatedServingEngine(ServeEngineConfig(
         n_server_groups=8, n_batches=4, batch_size=2, utilization=0.7,
         execute_model=False, seed=0, speculation_quantile=0.9,
     ))
-    assert eng.tuner.speculation_quantiles == (0.9,)
+    assert eng.tuner.policy_candidates == (
+        PolicyCandidate("clone", quantile=0.9),
+    )
 
 
 def test_engine_trace_arrival_kind_from_config():

@@ -161,11 +161,12 @@ def test_plain_and_speculative_sweep_jax_matches_numpy():
     assert s_np.backend == "numpy" and s_jx.backend == "jax"
     _dist_close(s_np.samples, s_jx.samples)
 
-    q_np = S.sweep_sojourn_speculative(_DISTS, quantiles=(None, 0.8), **_KW)
-    q_jx = S.sweep_sojourn_speculative(_DISTS, quantiles=(None, 0.8),
-                                       backend="jax", **_KW)
+    clone = (PolicyCandidate(), PolicyCandidate("clone", quantile=0.8))
+    q_np = S.sweep_sojourn_policies(_DISTS, policies=clone, **_KW)
+    q_jx = S.sweep_sojourn_policies(_DISTS, policies=clone, backend="jax",
+                                    **_KW)
     _dist_close(q_np.samples, q_jx.samples)
-    np.testing.assert_allclose(q_np.clone_fraction, q_jx.clone_fraction,
+    np.testing.assert_allclose(q_np.extra_fraction, q_jx.extra_fraction,
                                atol=2e-2)
 
 

@@ -22,10 +22,11 @@ from repro.core import (
     TunerConfig,
     simulate_sojourn,
     simulate_sojourn_policies,
+    straggler_portfolio,
     sweep_sojourn,
     sweep_sojourn_policies,
-    sweep_sojourn_speculative,
 )
+from repro.core.simulator import _sojourn_recursion_speculative
 from repro.serving import (
     ClonePolicy,
     EventDrivenMaster,
@@ -37,7 +38,6 @@ from repro.serving import (
     ReplicatedServingEngine,
     Request,
     ServeEngineConfig,
-    SpeculationPolicy,
 )
 
 N_FLEET = 16
@@ -67,9 +67,7 @@ def test_objective_policy_portfolio_validation():
     with pytest.raises(ValueError):
         Objective(policies=pols)  # needs load
     with pytest.raises(ValueError):
-        Objective(
-            utilization=0.5, policies=pols, speculation_quantiles=(0.9,)
-        )  # mutually exclusive axes
+        Objective(utilization=0.5, policies=())
     ok = Objective(utilization=0.5, policies=pols)
     # a plain-replication baseline always rides the portfolio
     assert ok.policies[0] == PolicyCandidate()
@@ -195,15 +193,6 @@ def test_noop_policy_matches_no_policy():
     assert outs[0] == outs[1]
 
 
-def test_speculation_and_straggler_policy_kwargs_are_exclusive():
-    with pytest.raises(ValueError):
-        EventDrivenMaster(
-            2, lambda job, g: np.array([1.0]),
-            speculation=SpeculationPolicy(threshold=lambda job: 1.0),
-            straggler_policy=ClonePolicy(threshold=lambda job: 1.0),
-        )
-
-
 # -- CRN parity of the policy sweep -------------------------------------------
 
 def test_disabled_policies_bit_identical_to_plain_sweep():
@@ -230,27 +219,46 @@ def test_disabled_policies_bit_identical_to_plain_sweep():
 
 
 def test_clone_policy_cell_bit_identical_to_speculative_sweep():
+    """A clone cell is the clone-attack recursion run on the sweep's draw
+    order (arrivals, primary matrix, clone matrix), triggered at the
+    set-service quantile, bit for bit."""
     policies = (PolicyCandidate("clone", quantile=0.9),)
+    n_jobs, rate = 1_200, 8.0
     res = sweep_sojourn_policies(
-        FLEET_DIST, N_FLEET, arrival_rate=8.0, policies=policies,
-        n_jobs=1_200, seed=5,
+        FLEET_DIST, N_FLEET, arrival_rate=rate, policies=policies,
+        n_jobs=n_jobs, seed=5,
     )
-    spec = sweep_sojourn_speculative(
-        FLEET_DIST, N_FLEET, arrival_rate=8.0, quantiles=(None, 0.9),
-        n_jobs=1_200, seed=5,
-    )
+    rng = np.random.default_rng(5)
+    arrivals = np.cumsum(rng.standard_exponential(n_jobs)) / rate
+    core = FLEET_DIST.delta + rng.standard_exponential(
+        (n_jobs, N_FLEET)
+    ) / FLEET_DIST.mu
+    clone_core = FLEET_DIST.delta + rng.standard_exponential(
+        (n_jobs, N_FLEET)
+    ) / FLEET_DIST.mu
     pi = res.policies.index(policies[0])
-    for s in range(len(res.splits)):
+    for s, b in enumerate(res.splits):
+        r = N_FLEET // b
+        svc = core.reshape(n_jobs, b, r).min(axis=2)
+        clone_svc = clone_core.reshape(n_jobs, b, r).min(axis=2)
+        sojourn, _ = _sojourn_recursion_speculative(
+            arrivals, svc, clone_svc, b, float(np.quantile(svc, 0.9))
+        )
         np.testing.assert_array_equal(
-            res.samples[0, s, pi], spec.samples[0, s, 1]
+            res.samples[0, s, pi], sojourn[n_jobs // 10:]
         )
 
 
-def test_policy_sweep_cells_match_single_sim():
-    policies = (
+@pytest.mark.parametrize("policies", [
+    (
         PolicyCandidate("relaunch", quantile=0.9),
         PolicyCandidate("hedged", hedge_fraction=0.3),
-    )
+    ),
+    (PolicyCandidate(), PolicyCandidate("clone", quantile=0.9)),
+], ids=["relaunch_hedged", "none_clone"])
+def test_policy_sweep_cells_match_single_sim(policies):
+    """CRN contract: every (B, policy) cell of the batched sweep is
+    bit-identical to the per-B call with the same candidates and seed."""
     res = sweep_sojourn_policies(
         FLEET_DIST, N_FLEET, arrival_rate=8.0, policies=policies,
         n_jobs=1_000, seed=4, feasible_b=(2, 4),
@@ -325,8 +333,7 @@ def test_empirical_planner_rate_aware_bootstrap():
     """EmpiricalPlanner consumes rate skew directly (PR 8): the bootstrap
     sweep couples each resample to the shared draws divided by per-worker
     rates and scores every B under the rate-aware placement the plan
-    emits.  Only the LEGACY speculation_quantiles axis keeps the loud
-    guard (pointing at the policy axis / HeterogeneousPlanner)."""
+    emits, and so does a clone trigger on the policy axis."""
     spec = ClusterSpec(
         n_workers=8, dist=Exponential(mu=2.0),
         rates=tuple(np.linspace(0.5, 1.5, 8)),
@@ -340,13 +347,13 @@ def test_empirical_planner_rate_aware_bootstrap():
     uniform = dataclasses.replace(spec, rates=None)
     plan_u = planner.plan(uniform, Objective(metric="mean"))
     assert plan.score != plan_u.score
-    # the one unsupported combo still fails loudly
-    with pytest.raises(ValueError, match="HeterogeneousPlanner"):
-        planner.plan(
-            spec,
-            Objective(metric="p99", utilization=0.5,
-                      speculation_quantiles=(0.9,)),
-        )
+    # a clone trigger rides the rate-aware bootstrap sweep
+    clone = PolicyCandidate("clone", quantile=0.9)
+    plan_c = planner.plan(
+        spec,
+        Objective(metric="p99", utilization=0.5, policies=(clone,)),
+    )
+    assert plan_c.policy in (PolicyCandidate(), clone)
     # uniform fleets still plan fine
     ok = ClusterSpec(n_workers=8, dist=Exponential(mu=2.0))
     assert EmpiricalPlanner(
@@ -430,11 +437,11 @@ def test_plan_policy_lands_and_mirrors_clone_trigger():
         Objective(metric="p99", utilization=0.7, policies=pols),
     )
     assert plan.policy is not None
-    # legacy mirror: speculation_quantile is the clone trigger or None
+    # the winner is one of the offered candidates or the 'none' baseline,
+    # and a clone winner carries its trigger on Plan.policy itself
+    assert plan.policy in (PolicyCandidate(), *pols)
     if plan.policy.kind == "clone":
-        assert plan.speculation_quantile == plan.policy.quantile
-    else:
-        assert plan.speculation_quantile is None
+        assert plan.policy.quantile == 0.9
 
 
 def test_portfolio_beats_or_ties_plain_baseline_by_construction():
@@ -454,65 +461,81 @@ def test_portfolio_beats_or_ties_plain_baseline_by_construction():
 
 # -- online adoption (engine + tuner) -----------------------------------------
 
-def _portfolio_engine(**kw):
-    return ReplicatedServingEngine(ServeEngineConfig(
-        n_server_groups=8, n_batches=8, batch_size=2, delta=0.02, mu=2.0,
-        utilization=0.7, execute_model=False, seed=0, tuner=True,
-        planner_mode="simulate",
-        policy_candidates=(
-            PolicyCandidate("clone", quantile=0.9),
-            PolicyCandidate("relaunch", quantile=0.9),
-            PolicyCandidate("hedged", hedge_fraction=0.3),
-        ),
-        **kw,
-    ))
+_ENGINE = dict(
+    n_server_groups=8, n_batches=8, batch_size=2, delta=0.02, mu=2.0,
+    utilization=0.7, execute_model=False, seed=0, tuner=True,
+    planner_mode="simulate",
+)
+_PORTFOLIO = (
+    PolicyCandidate("clone", quantile=0.9),
+    PolicyCandidate("relaunch", quantile=0.9),
+    PolicyCandidate("hedged", hedge_fraction=0.3),
+)
 
 
-def test_engine_adopts_replan_policy(monkeypatch):
+@pytest.mark.parametrize("engine_kw, won, live, master_type", [
+    ({"policy_candidates": _PORTFOLIO},
+     PolicyCandidate("hedged", hedge_fraction=0.3),
+     PolicyCandidate("hedged", hedge_fraction=0.3), HedgedDispatchPolicy),
+    # clone mode: plain replication won, so the clone is switched off
+    ({"speculation_quantile": 0.8}, PolicyCandidate(), None, None),
+], ids=["portfolio_hedged", "clone_disabled"])
+def test_engine_adopts_replan_policy(
+    monkeypatch, engine_kw, won, live, master_type
+):
     """A load-aware re-plan that swept (B, policy) cells installs the
-    winning candidate on the live engine — here a hedged policy."""
-    eng = _portfolio_engine()
+    winning candidate on the live engine — including "don't mitigate at
+    this B"."""
+    eng = ReplicatedServingEngine(ServeEngineConfig(**_ENGINE, **engine_kw))
     assert eng.objective.policies is not None
     plan = eng.planner.plan(
         ClusterSpec(n_workers=8, dist=eng.dist),
         Objective(metric="mean", arrival_rate=4.0,
-                  policies=eng.sc.policy_candidates),
+                  policies=eng.objective.policies),
     )
     plan = dataclasses.replace(
-        plan,
-        policy=PolicyCandidate("hedged", hedge_fraction=0.3),
-        speculation_quantile=None,
-        replication=ReplicationPlan(n_data=8, n_batches=4),
+        plan, policy=won, replication=ReplicationPlan(n_data=8, n_batches=4),
     )
     rp = RescalePlan(old_batches=8, new_batches=4, predicted_old=1.0,
                      predicted_new=0.5, fit=None, step=0, plan=plan)
     monkeypatch.setattr(eng.tuner, "maybe_replan", lambda: rp)
     eng.serve(20)
     assert eng.plan.n_batches == 4
-    assert eng.policy == PolicyCandidate("hedged", hedge_fraction=0.3)
-    assert isinstance(eng._speculation_policy(), HedgedDispatchPolicy)
-    assert eng.speculation_quantile is None  # mirror: not a clone
+    assert eng.policy == live
+    master = eng._speculation_policy()
+    if master_type is None:
+        assert master is None
+    else:
+        assert isinstance(master, master_type)
 
 
-def test_engine_adopts_policy_switch_at_same_b(monkeypatch):
-    """A sweep that keeps B but flips the best candidate (clone ->
-    relaunch) still updates the engine — a policy change needs no drain."""
-    eng = _portfolio_engine(speculation_quantile=0.8)
+@pytest.mark.parametrize("engine_kw, won, master_type", [
+    ({"policy_candidates": _PORTFOLIO},
+     PolicyCandidate("relaunch", quantile=0.9), RelaunchPolicy),
+    ({}, PolicyCandidate("clone", quantile=0.95), ClonePolicy),
+], ids=["clone_to_relaunch", "clone_trigger_change"])
+def test_engine_adopts_policy_switch_at_same_b(
+    monkeypatch, engine_kw, won, master_type
+):
+    """A sweep that keeps B but flips the best candidate (another kind, or
+    another trigger) still updates the engine — a policy change needs no
+    drain."""
+    eng = ReplicatedServingEngine(ServeEngineConfig(
+        **_ENGINE, speculation_quantile=0.8, **engine_kw
+    ))
     assert eng.policy == PolicyCandidate("clone", quantile=0.8)
     lp = eng.planner.plan(
         ClusterSpec(n_workers=8, dist=eng.dist, feasible_b=(8,)),
         Objective(metric="mean", arrival_rate=4.0,
-                  policies=eng.sc.policy_candidates),
+                  policies=eng.objective.policies),
     )
-    lp = dataclasses.replace(
-        lp, policy=PolicyCandidate("relaunch", quantile=0.9)
-    )
+    lp = dataclasses.replace(lp, policy=won)
     monkeypatch.setattr(eng.tuner, "maybe_replan", lambda: None)
     eng.tuner.last_plan = lp
     eng.serve(10)
     assert eng.plan.n_batches == 8  # no move
-    assert eng.policy == PolicyCandidate("relaunch", quantile=0.9)
-    assert isinstance(eng._speculation_policy(), RelaunchPolicy)
+    assert eng.policy == won
+    assert isinstance(eng._speculation_policy(), master_type)
 
 
 def test_tuner_objective_carries_policy_portfolio():
@@ -526,12 +549,46 @@ def test_tuner_objective_carries_policy_portfolio():
     tuner.observe_load(3.0)
     obj = tuner.objective()
     assert obj.policies == (PolicyCandidate(), *pols)
-    assert obj.speculation_quantiles is None
     assert len(obj.arrivals) == 32
-    with pytest.raises(ValueError):
-        StragglerTuner(
-            ReplicationPlan(n_data=8, n_batches=4),
-            TunerConfig(mode="simulate"),
-            policy_candidates=pols,
-            speculation_quantiles=(0.9,),
-        )
+
+
+# -- the live policy -> portfolio rule ----------------------------------------
+
+_CLONE_80 = PolicyCandidate("clone", quantile=0.8)
+_RELAUNCH_90 = PolicyCandidate("relaunch", quantile=0.9)
+
+
+@pytest.mark.parametrize("live, candidates, portfolio", [
+    (_CLONE_80, (_RELAUNCH_90,), (_RELAUNCH_90,)),  # the portfolio wins
+    (_CLONE_80, None, (_CLONE_80,)),  # the live policy alone
+    (None, None, None),  # nothing to score: plain replication
+], ids=["portfolio", "live", "off"])
+def test_straggler_portfolio_rule(live, candidates, portfolio):
+    assert straggler_portfolio(live, candidates) == portfolio
+
+
+def test_clone_mode_engine_plans_with_the_stability_gate():
+    """An engine in clone mode scores (none, clone q) as a portfolio, so a
+    clone cell whose charged utilization reaches 1 cannot win — even where
+    its finite-window samples look better than plain replication."""
+    clone = PolicyCandidate("clone", quantile=0.5)
+    eng = ReplicatedServingEngine(ServeEngineConfig(
+        n_server_groups=16, n_batches=16, batch_size=4, delta=0.5, mu=2.0,
+        utilization=0.8, execute_model=False, seed=0,
+        planner_mode="simulate", speculation_quantile=0.5,
+    ))
+    obj, spec = eng.objective, eng.cluster_spec
+    assert obj.policies == (PolicyCandidate(), clone)
+    assert eng.tuner.policy_candidates == (clone,)
+    assert obj.charged_utilization(spec, clone) >= 1.0
+    plan = eng.planner.plan(spec, obj)
+    assert plan.policy == PolicyCandidate()
+    # ungated, the clone cell would have won at the chosen B
+    res = sweep_sojourn_policies(
+        spec.dist, spec.n_workers, arrival_rate=obj.offered_rate(spec),
+        policies=obj.policies, n_jobs=eng.planner.n_trials,
+        seed=eng.planner.seed, feasible_b=spec.feasible_batches(),
+        job_load=obj.job_load,
+    )
+    s = res.splits.index(plan.n_batches)
+    assert res.samples[0, s, 1].mean() < res.samples[0, s, 0].mean()
