@@ -419,8 +419,7 @@ def decode_step(cfg: ArchConfig, shard: Shard, params, state, token,
     x = L.embed_tokens(params["embed"], token)
     positions = cache_len + jnp.zeros((1,), jnp.int32)
     if cfg.mla is not None:
-        x, kv = MLA.decode(cfg, shard, params, x, state["kv"], cache_len,
-                           positions)
+        x, kv = MLA.decode(cfg, params, x, state["kv"], cache_len, positions)
         state = {"kv": kv}
     elif cfg.family in ("dense", "vlm", "moe"):
         # the stacked caches ride in the scan's carry, each layer writing

@@ -211,10 +211,10 @@ def _block(cfg: ArchConfig, shard: Shard, lp, x, positions):
     return x + y, latent, aux
 
 
-def _block_decode(cfg: ArchConfig, shard: Shard, lp, x, cache, cache_len,
-                  positions):
+def _block_decode(cfg: ArchConfig, lp, x, cache, cache_len, positions, ffn):
     """One-token block: writes the token's entry at ``cache_len`` and
-    attends over ``cache_len + 1`` positions.  Returns (x, cache)."""
+    attends over ``cache_len + 1`` positions; ``ffn`` is the block's MLP or
+    expert layer.  Returns (x, cache)."""
     h1 = L.apply_norm(cfg, lp["ln1"], x)
     with jax.named_scope("mla.project"):
         q_nope, q_pe, latent = project(cfg, lp["attn"], h1, positions)
@@ -223,8 +223,7 @@ def _block_decode(cfg: ArchConfig, shard: Shard, lp, x, cache, cache_len,
     with jax.named_scope("mla.attend"):
         x = x + _out(lp["attn"], attend_absorbed(
             cfg, lp["attn"], q_nope, q_pe, cache, cache_len + 1))
-    y, _ = _ffn(cfg, shard, lp, L.apply_norm(cfg, lp["ln2"], x))
-    return x + y, cache
+    return x + ffn(L.apply_norm(cfg, lp["ln2"], x)), cache
 
 
 def forward(cfg: ArchConfig, shard: Shard, params, x, positions,
@@ -243,18 +242,30 @@ def forward(cfg: ArchConfig, shard: Shard, params, x, positions,
     return x, cache, aux + auxs.sum()
 
 
-def decode(cfg: ArchConfig, shard: Shard, params, x, cache, cache_len,
-           positions):
+def decode(cfg: ArchConfig, params, x, cache, cache_len, positions):
     """One token through the stack against ``cache`` (L, b, S, r + rope):
-    (y, updated cache)."""
-    x, c0 = _block_decode(cfg, shard, params["dense_block"], x, cache[0],
-                          cache_len, positions)
+    (y, updated cache).
+
+    The MoE layers' held experts stay out of the layer scan's inputs: the
+    scan closes over their whole stacks and hands the expert layer its
+    index, at which the grouped-matmul kernel reads them in place (a
+    scanned slice of a stack would be copied out, every layer and step)."""
+    dense = params["dense_block"]
+    x, c0 = _block_decode(cfg, dense, x, cache[0], cache_len, positions,
+                          lambda h: L.apply_mlp(cfg, dense["mlp"], h))
+    blocks = params["blocks"]
+    stacks = {k: blocks["moe"][k] for k in M.HELD_STACKS}
+    per_layer = {**blocks, "moe": {k: v for k, v in blocks["moe"].items()
+                                   if k not in stacks}}
 
     def body(h, xs):
-        lp, c = xs
-        return _block_decode(cfg, shard, lp, h, c, cache_len, positions)
+        lp, c, layer = xs
+        return _block_decode(
+            cfg, lp, h, c, cache_len, positions,
+            lambda y: M.apply_held_decode(cfg, lp["moe"], stacks, layer, y))
 
-    x, cs = jax.lax.scan(body, x, (params["blocks"], cache[1:]))
+    layers = jnp.arange(cfg.n_layers - 1, dtype=jnp.int32)
+    x, cs = jax.lax.scan(body, x, (per_layer, cache[1:], layers))
     return x, jnp.concatenate([c0[None], cs])
 
 

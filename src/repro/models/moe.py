@@ -6,11 +6,16 @@ Dropless, over the experts this chip holds (deepseek-v2-lite; the first
 ``n_held`` of the router's): a float32 softmax over all the router's
 experts and a greedy top-k; the (token,
 expert) pairs whose expert is held are sorted by expert to the front, and
-three grouped matmuls (``jax.lax.ragged_dot``) compute a SwiGLU for each of
-them, however many land on one expert.  Pairs routed to experts held
-elsewhere form no group and are not computed: the layer passes on the part
-of the result that its own experts give, as an expert-parallel rank does
-before the exchange between chips (which one chip does not run).
+three grouped matmuls compute a SwiGLU for each of them, however many land
+on one expert.  Pairs routed to experts held elsewhere form no group and
+are not computed: the layer passes on the part of the result that its own
+experts give, as an expert-parallel rank does before the exchange between
+chips (which one chip does not run).  Prefill and training
+(``apply_moe``) run ``jax.lax.ragged_dot`` over the layer's experts;
+the decode step (``apply_held_decode``, from ``mla.decode``'s layer scan)
+runs the ``held_experts_gmm`` kernel over the whole stack of every layer's
+experts at the layer's index, which reads only the experts that a pair
+goes to (``touched_experts``) and copies no layer's stack out.
 
 Sort-based capacity dispatch (MegaBlocks/MaxText style) — never materializes
 the (T, E, C) one-hot of GShard:
@@ -28,18 +33,24 @@ The router adds the Switch-style load-balancing auxiliary loss.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig, MoEConfig, ShardingPolicy
+from repro.kernels.held_experts.ops import held_experts_gmm
 from repro.models import layers as L
 from repro.models.sharding import Shard
 
-__all__ = ["init_moe", "moe_specs", "apply_moe", "router_capacity"]
+__all__ = ["init_moe", "moe_specs", "apply_moe", "apply_held_decode",
+           "touched_experts", "router_capacity", "HELD_STACKS"]
 
 from jax.sharding import PartitionSpec as P
+
+# a dropless layer's expert weights, (held, d, f), (held, d, f), (held, f, d)
+HELD_STACKS = ("wi_gate", "wi_up", "wo")
 
 
 def router_capacity(moe: MoEConfig, n_tokens: int) -> int:
@@ -112,32 +123,74 @@ def _aux_loss(moe: MoEConfig, probs, gate_e):
     return moe.aux_loss_weight * e * jnp.sum(fe * me)
 
 
+def _sort_pairs(moe: MoEConfig, gate_e):
+    """The (token, expert) pairs of gate ids (t, k), the held ones sorted by
+    expert to the front (the rest form no group): (order, the token of
+    each sorted pair, each held expert's group size (held,) int32)."""
+    held = moe.held
+    group = jnp.minimum(gate_e.reshape(-1), held)
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
+    return order, order // moe.top_k, sizes
+
+
+def touched_experts(sizes):
+    """The decode kernel's visit list from the group sizes (E,): the experts
+    with at least one pair, in ascending order, padded to E with the last
+    of them (expert 0 where none has a pair), and how many there are.  The
+    kernel fetches weight blocks of ``ids[:count]`` and of no other expert
+    (``repro.kernels.held_experts``)."""
+    touched = sizes > 0
+    count = touched.sum(dtype=jnp.int32)
+    ids = jnp.argsort(~touched, stable=True).astype(jnp.int32)
+    last = ids[jnp.maximum(count - 1, 0)]
+    return jnp.where(jnp.arange(sizes.shape[0]) < count, ids, last), count
+
+
 def _held_experts(moe: MoEConfig, params, xt, gate_w, gate_e):
     """xt (t, d); gate (t, k) -> (t, d) float32: for every (token, expert)
     pair whose expert this chip holds, the gate weight times the expert's
-    SwiGLU of the token, summed per token.  Nothing is dropped."""
+    SwiGLU of the token, summed per token.  Nothing is dropped.  Prefill
+    and training: three ``ragged_dot`` over the layer's experts."""
     t, d = xt.shape
-    held = moe.held
-    ids = gate_e.reshape(-1)
-    mine = ids < held
-    # held pairs sorted by expert to the front; the rest form no group
-    group = jnp.minimum(ids, held)
-    order = jnp.argsort(group, stable=True)
-    sizes = jnp.bincount(group, length=held + 1)[:held].astype(jnp.int32)
-    rows = order // moe.top_k  # the token of each sorted pair
+    order, rows, sizes = _sort_pairs(moe, gate_e)
     xs = xt[rows]
     g = jax.lax.ragged_dot(xs, params["wi_gate"], sizes)
     u = jax.lax.ragged_dot(xs, params["wi_up"], sizes)
     h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
     y = jax.lax.ragged_dot(h, params["wo"], sizes)
     # rows in no group are left undefined by the grouped matmul: select
+    mine = gate_e.reshape(-1)[order] < moe.held
     w = gate_w.reshape(-1)[order]
-    y = jnp.where(mine[order][:, None], y.astype(jnp.float32) * w[:, None],
-                  0.0)
+    y = jnp.where(mine[:, None], y.astype(jnp.float32) * w[:, None], 0.0)
     return jnp.zeros((t, d), jnp.float32).at[rows].add(y)
 
 
-def _apply_dropless(cfg: ArchConfig, params, x):
+def _held_experts_in_place(stacks, layer, moe: MoEConfig, params, xt, gate_w,
+                           gate_e):
+    """``_held_experts`` for decode, over layer ``layer`` of ``stacks``
+    (every MoE layer's held experts, ``HELD_STACKS``, (L, held, ., .)):
+    three ``held_experts_gmm`` calls, which read only the experts that
+    have a pair, in place."""
+    del params
+    t, d = xt.shape
+    order, rows, sizes = _sort_pairs(moe, gate_e)
+    experts, count = touched_experts(sizes)
+
+    def gmm(x, w):
+        return held_experts_gmm(x, w, layer, sizes, experts, count)
+
+    xs = xt[rows]
+    g = gmm(xs, stacks["wi_gate"])
+    u = gmm(xs, stacks["wi_up"])
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(xs.dtype) * u
+    # rows in no group are zero
+    y = gmm(h, stacks["wo"]).astype(jnp.float32)
+    w = gate_w.reshape(-1)[order]
+    return jnp.zeros((t, d), jnp.float32).at[rows].add(y * w[:, None])
+
+
+def _apply_dropless(cfg: ArchConfig, params, x, experts=_held_experts):
     moe = cfg.moe
     b, s, d = x.shape
     xt = x.reshape(b * s, d)
@@ -145,12 +198,21 @@ def _apply_dropless(cfg: ArchConfig, params, x):
         probs, gate_w, gate_e = _route(moe, xt, params["router"])
         aux = _aux_loss(moe, probs, gate_e)
     with jax.named_scope("moe.experts"):
-        y = _held_experts(moe, params, xt, gate_w, gate_e)
+        y = experts(moe, params, xt, gate_w, gate_e)
     y = y.astype(x.dtype).reshape(b, s, d)
     if moe.n_shared > 0:
         with jax.named_scope("moe.shared"):
             y = y + L.apply_mlp(cfg, params["shared"], x)
     return y, aux
+
+
+def apply_held_decode(cfg: ArchConfig, params, stacks, layer, x):
+    """The dropless layer for one decode step, x (b, 1, d) -> (b, 1, d):
+    ``params`` the layer's router and shared experts, ``stacks`` every MoE
+    layer's held experts, read at ``layer`` in place (see
+    ``_held_experts_in_place``)."""
+    experts = functools.partial(_held_experts_in_place, stacks, layer)
+    return _apply_dropless(cfg, params, x, experts)[0]
 
 
 def apply_moe(

@@ -23,6 +23,8 @@ from repro.kernels.decode_attention import kernel as dec_kernel
 from repro.kernels.decode_attention import ops as dec_ops
 from repro.kernels.flash_attention import kernel as flash_kernel
 from repro.kernels.flash_attention import ops as flash_ops
+from repro.kernels.held_experts import kernel as held_kernel
+from repro.kernels.held_experts import ops as held_ops
 from repro.kernels.sojourn_sweep import kernel as sweep_kernel
 from repro.kernels.sojourn_sweep import ops as sweep_ops
 from repro.kernels.ssm_scan import kernel as ssm_kernel
@@ -62,6 +64,8 @@ PALLAS_ENTRY_POINTS = [
     dec_kernel.decode_attention_kernel_call,
     ssm_ops.ssd_scan,
     ssm_kernel.ssd_scan_kernel_call,
+    held_ops.held_experts_gmm,
+    held_kernel.held_experts_gmm_kernel_call,
 ]
 
 

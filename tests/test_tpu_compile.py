@@ -209,3 +209,51 @@ def test_dense_decode_updates_the_cache_in_place(spec):
     assert not [c for blk in blocks if blk not in entry
                 for c in _copies(blk, layer)]
     assert len(_copies(entry[0], cfg.n_layers * layer)) <= 2
+
+
+def _ops_with(text, elements):
+    """(computation, instruction) of every copy, fusion or dynamic slice in
+    the program whose (first) output holds ``elements`` elements."""
+    out = []
+    for block in text.split("\n\n"):
+        head = block.strip().split(" ", 1)[0] if block.strip() else ""
+        for line in block.splitlines():
+            m = re.match(r"\s*(?:ROOT )?%(\S+) = \S+?\[([\d,]*)\]\S* "
+                         r"(copy|copy-start|fusion|dynamic-slice)\(", line)
+            if m and math.prod(
+                    int(d) for d in m.group(2).split(",") if d) == elements:
+                out.append((head, m.group(1)))
+    return out
+
+
+def test_mla_decode_reads_the_held_experts_in_place(spec, monkeypatch):
+    """deepseek-v2-lite-ep8's decode step at the chat cell's shape: Mosaic
+    takes the held-expert kernel at its block sizes, three calls in the
+    layer loop, and no op copies or slices out a layer's expert stack (8 x
+    2048 x 1408, which a scanned stack fed to ``ragged_dot`` was) or the
+    whole stack."""
+    from repro.configs import get_config
+    from repro.kernels.held_experts import kernel as HK
+    from repro.models import Shard, decode_state_shapes, decode_step, init_params
+
+    # the kernel asks the default backend, which is the CPU here
+    monkeypatch.setattr(HK, "resolve_interpret", lambda interpret=None: False)
+    cfg = get_config("deepseek-v2-lite-ep8")
+    b, s = CHAT["batch"], CHAT["max_len"]
+    shapes = lambda tree: jax.tree.map(  # noqa: E731
+        lambda x: spec(x.shape, x.dtype), tree)
+    params = shapes(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    state = shapes(decode_state_shapes(cfg, b, s))
+    assert state["kv"].shape == (27, 8, 1152, 576)
+    text = jax.jit(
+        lambda p, st, t, c: decode_step(cfg, Shard.local(), p, st, t, c)
+    ).lower(params, state, spec((b, 1), jnp.int32),
+            spec((), jnp.int32)).compile().as_text()
+
+    calls = re.findall(r"%held_experts_gmm\S* = .*custom_call_target="
+                       r"\"tpu_custom_call\"", text)
+    assert len(calls) == 3
+    layer = cfg.moe.held * cfg.d_model * cfg.moe.d_expert
+    assert not _ops_with(text, layer)
+    assert not _ops_with(text, (cfg.n_layers - 1) * layer)
